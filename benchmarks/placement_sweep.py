@@ -139,6 +139,9 @@ def main() -> None:
     )
     args = parser.parse_args()
 
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     records = []
     for label, fn in (
         ("4-socket fully-connected", numa_placement_sweep),

@@ -189,7 +189,10 @@ def _placement_scores(  # bpi weights stay traced: one compile per machine
             # diagonal (self) pairs have empty routes => all-zero incidence
             # rows, so local flows drop out of the link charge on their own
             cross = (flows_r + flows_w).reshape(-1)
-            utils.append((cross @ route_inc) / link_caps)
+            charge = jnp.matmul(
+                cross, route_inc, precision=jax.lax.Precision.HIGHEST
+            )
+            utils.append(charge / link_caps)
         worst = jnp.concatenate(utils).max()
         rate = jnp.minimum(1.0, 1.0 / jnp.maximum(worst, 1e-9))
         throughput = nw.sum() * rate

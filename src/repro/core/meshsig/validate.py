@@ -20,6 +20,7 @@ import time
 from pathlib import Path
 
 import jax
+from jax.sharding import AxisType
 
 from repro.configs.base import SHAPES, get_config
 from repro.core.meshsig.advisor import CHIP_V5E, ChipSpec, rank_meshes
@@ -68,7 +69,10 @@ def prediction_errors(
 def profile_mesh(cfg, shape, axes: dict) -> tuple[MeshProfile, float]:
     from repro.launch.dryrun import lower_cell  # sets the same XLA_FLAGS
 
-    mesh = jax.make_mesh(tuple(axes.values()), tuple(axes.keys()))
+    mesh = jax.make_mesh(
+        tuple(axes.values()), tuple(axes.keys()),
+        axis_types=(AxisType.Auto,) * len(axes),
+    )
     t0 = time.time()
     with mesh_lib.cell_context(mesh, cfg, shape):
         jitted, args, _ = lower_cell(cfg, shape, mesh)

@@ -573,7 +573,11 @@ def seed_parameters(
     pair_r = _pair_flows(samples, rr)
     pair_w = _pair_flows(samples, rw)
     incidence = jnp.asarray(template.topology.route_incidence())  # (s*s, L)
-    charge = (pair_r + pair_w).reshape(samples.placements.shape[0], s * s) @ incidence
+    charge = jnp.matmul(
+        (pair_r + pair_w).reshape(samples.placements.shape[0], s * s),
+        incidence,
+        precision=jax.lax.Precision.HIGHEST,  # f32 seeds, not bf16 ones
+    )
     link_seed = np.asarray(floored(charge.max(0)))
 
     # attenuation: a multi-hop pair's flow obeys flow <= base * att**(h-1),

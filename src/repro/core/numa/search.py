@@ -205,7 +205,10 @@ def relaxed_work_rate(
         inc = jnp.asarray(
             np.asarray(topo.route_incidence(), np.float32).reshape(s, s, n_links)
         )
-        lu = jnp.einsum("ckj,kjl->ckl", (ru + wu) * offdiag, inc)
+        lu = jnp.einsum(
+            "ckj,kjl->ckl", (ru + wu) * offdiag, inc,
+            precision=jax.lax.Precision.HIGHEST,  # as the exact fill's slab
+        )
     else:
         lu = jnp.zeros((C, s, 0), dtype)
     dense = jnp.concatenate(

@@ -67,6 +67,11 @@ from repro.core.numa.machine import MachineSpec, canonical_bank_assignment
 from repro.core.numa.workload import Workload
 
 _EPS = 1e-12
+# Every f32 contraction of the solver runs at full f32 precision.  A TPU's
+# default matmul precision rounds f32 operands to bf16, far coarser than
+# the fill's 1e-6 bottleneck tie tolerance: ties would flip, and with them
+# which groups freeze and every rate downstream.
+_HI = jax.lax.Precision.HIGHEST
 
 
 class SimulationResult(NamedTuple):
@@ -203,7 +208,9 @@ def _resource_tensor(
     n_links = topo.n_links
     if n_links and multipath:
         inc = jnp.asarray(topo.route_incidence(multipath=True))  # (s*s, L)
-        link_usage = (rr_remote + ww_remote).reshape(n, s * s) @ inc
+        link_usage = jnp.matmul(
+            (rr_remote + ww_remote).reshape(n, s * s), inc, precision=_HI
+        )
     elif n_links:
         ends_i = np.asarray([e[0] for e in topo.link_ends])
         ends_j = np.asarray([e[1] for e in topo.link_ends])
@@ -216,7 +223,7 @@ def _resource_tensor(
         if not topo.is_fully_direct:
             routed = jnp.asarray(topo.route_incidence_multihop())  # (s*s, L)
             cross = (rr_remote + ww_remote).reshape(n, s * s)
-            link_usage = link_usage + cross @ routed
+            link_usage = link_usage + jnp.matmul(cross, routed, precision=_HI)
     else:
         link_usage = jnp.zeros((n, 0))
 
@@ -324,9 +331,13 @@ def simulate_reference(
     rates = _progressive_fill(usage, caps, iterations)
 
     onehot = jax.nn.one_hot(node_of, s)
-    read_flows = onehot.T @ (rates[:, None] * read_unit) * elapsed
-    write_flows = onehot.T @ (rates[:, None] * write_unit) * elapsed
-    instructions = onehot.T @ (rates * rate_of) * elapsed
+    read_flows = jnp.matmul(
+        onehot.T, rates[:, None] * read_unit, precision=_HI
+    ) * elapsed
+    write_flows = jnp.matmul(
+        onehot.T, rates[:, None] * write_unit, precision=_HI
+    ) * elapsed
+    instructions = jnp.matmul(onehot.T, rates * rate_of, precision=_HI) * elapsed
 
     return _finalize_result(
         rates, read_flows, write_flows, instructions, n_per_node,
@@ -517,7 +528,9 @@ def _group_resource_tensor(
             topo.route_incidence(multipath=multipath)
         ).reshape(s, s, topo.n_links)
         inc_rows = jnp.asarray(inc[node_idx])  # (G, s, L) static constant
-        link_usage = jnp.einsum("gj,gjl->gl", rr_vals + ww_vals, inc_rows)
+        link_usage = jnp.einsum(
+            "gj,gjl->gl", rr_vals + ww_vals, inc_rows, precision=_HI
+        )
     else:
         link_usage = jnp.zeros((G, 0))
 
@@ -714,14 +727,14 @@ def _progressive_fill_structured(
         active = ~frozen
         wt_frozen = (jnp.where(frozen, x, 0.0) * mult).astype(dtype)
         wt_active = jnp.where(active, mult, 0.0).astype(dtype)
-        fz_dense = wt_frozen @ dense
-        act_dense = wt_active @ dense
+        fz_dense = jnp.matmul(wt_frozen, dense, precision=_HI)
+        act_dense = jnp.matmul(wt_active, dense, precision=_HI)
         wf = wt_frozen.reshape(C, s)
         wa = wt_active.reshape(C, s)
-        fz_rr = jnp.einsum("ck,ckj->kj", wf, rem_read)
-        act_rr = jnp.einsum("ck,ckj->kj", wa, rem_read)
-        fz_ww = jnp.einsum("ck,ckj->kj", wf, rem_write)
-        act_ww = jnp.einsum("ck,ckj->kj", wa, rem_write)
+        fz_rr = jnp.einsum("ck,ckj->kj", wf, rem_read, precision=_HI)
+        act_rr = jnp.einsum("ck,ckj->kj", wa, rem_read, precision=_HI)
+        fz_ww = jnp.einsum("ck,ckj->kj", wf, rem_write, precision=_HI)
+        act_ww = jnp.einsum("ck,ckj->kj", wa, rem_write, precision=_HI)
 
         def lam_of(resid, act):
             return jnp.where(
@@ -741,8 +754,12 @@ def _progressive_fill_structured(
         bn_ww = lam_ww <= tol
         uses = (
             (dense * bn_d[None, :]).sum(1)
-            + jnp.einsum("ckj,kj->ck", rem_read, bn_rr.astype(dtype)).reshape(g)
-            + jnp.einsum("ckj,kj->ck", rem_write, bn_ww.astype(dtype)).reshape(g)
+            + jnp.einsum(
+                "ckj,kj->ck", rem_read, bn_rr.astype(dtype), precision=_HI
+            ).reshape(g)
+            + jnp.einsum(
+                "ckj,kj->ck", rem_write, bn_ww.astype(dtype), precision=_HI
+            ).reshape(g)
         ) > _EPS
         freeze_now = active & (uses | (lam_star >= 1.0))
         x = jnp.where(freeze_now, lam_star, x)
@@ -863,7 +880,7 @@ def simulate_grouped_batch(
         wu = comps.base_write + comps.il_write[:, :, None] * il_row[None, None, :]
         if n_links:
             cross = (ru + wu) * offdiag
-            lu = jnp.einsum("ckj,kjl->ckl", cross, inc)
+            lu = jnp.einsum("ckj,kjl->ckl", cross, inc, precision=_HI)
         else:
             lu = jnp.zeros((C, s, 0), dtype)
         return ru, wu, lu
@@ -874,7 +891,7 @@ def simulate_grouped_batch(
         # per-link charge of one unit of pt_row flow from node k (the
         # diagonal rows of inc are all-zero, so no off-diagonal mask needed)
         def pt_link(pt_row):
-            return jnp.einsum("j,kjl->kl", pt_row, inc)  # (s, L)
+            return jnp.einsum("j,kjl->kl", pt_row, inc, precision=_HI)  # (s, L)
     starts = tuple(int(v) for v in np.asarray(thread_classes, np.int64))
 
     def per_placement(p, sid):
@@ -901,8 +918,8 @@ def simulate_grouped_batch(
         )
         xg = x.reshape(C, s)
         weight = mult * xg
-        read_flows = jnp.einsum("ck,ckj->kj", weight, ru) * elapsed
-        write_flows = jnp.einsum("ck,ckj->kj", weight, wu) * elapsed
+        read_flows = jnp.einsum("ck,ckj->kj", weight, ru, precision=_HI) * elapsed
+        write_flows = jnp.einsum("ck,ckj->kj", weight, wu, precision=_HI) * elapsed
         instructions = (weight * node_rates[None, :]).sum(0) * elapsed
         return GroupedBatchResult(
             read_flows=read_flows,
@@ -1003,8 +1020,12 @@ def simulate(
     xg = x.reshape(C, s)
 
     weight = mult_f * xg  # (C, s): threads x shared group rate
-    read_flows = jnp.einsum("ck,ckj->kj", weight, read_unit) * elapsed
-    write_flows = jnp.einsum("ck,ckj->kj", weight, write_unit) * elapsed
+    read_flows = jnp.einsum(
+        "ck,ckj->kj", weight, read_unit, precision=_HI
+    ) * elapsed
+    write_flows = jnp.einsum(
+        "ck,ckj->kj", weight, write_unit, precision=_HI
+    ) * elapsed
     instructions = (weight * node_rates[None, :]).sum(0) * elapsed
 
     node_of = _thread_nodes(n_per_node, n)

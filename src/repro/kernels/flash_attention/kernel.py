@@ -30,8 +30,6 @@ from jax import Array
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 _NEG_INF = -1e30
 
 
@@ -159,7 +157,7 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

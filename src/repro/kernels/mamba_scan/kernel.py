@@ -23,8 +23,6 @@ from jax import Array
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(
     dt_ref,  # (1, c, bd)
@@ -89,7 +87,7 @@ def selective_scan(
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda bi, d, j: (bi, j, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_d, n), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,4 +1,6 @@
-"""Jit'd public wrapper for the selective-scan kernel."""
+"""Jit'd public wrapper for the selective-scan kernel.  ``interpret=True``
+runs it through the Pallas interpreter (the CPU test path); the default
+compiles it for the TPU."""
 
 from __future__ import annotations
 
@@ -11,10 +13,6 @@ from jax import Array
 from repro.kernels.mamba_scan.kernel import selective_scan
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssm_scan(
     dt: Array,
@@ -23,10 +21,8 @@ def ssm_scan(
     c: Array,
     x: Array,
     *,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Array:
-    if interpret is None:
-        interpret = not on_tpu()
     block_d = 512
     di = x.shape[-1]
     while di % block_d:

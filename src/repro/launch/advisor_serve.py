@@ -149,6 +149,9 @@ def main() -> None:
     args = parser.parse_args()
 
     from repro.core.numa import E7_4830_V3, make_machine
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     service = AdvisorService(
         max_batch=args.max_batch, max_wait_s=args.max_wait_ms / 1e3

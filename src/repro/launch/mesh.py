@@ -12,7 +12,7 @@ from __future__ import annotations
 import contextlib
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.parallel import context as ctx
@@ -27,7 +27,9 @@ SERVE_REPLICATION_LIMIT = 6 * GB
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the model code places tensors with with_sharding_constraint
+    # and shard_map, which jax.make_mesh's default Explicit axes reject.
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def candidate_mesh_axes(

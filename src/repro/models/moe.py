@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax import Array
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.parallel import context as ctx
 
@@ -247,7 +246,7 @@ def moe_ffn_a2a(cfg: ModelConfig, p: dict, x: Array) -> tuple[Array, Array]:
         ),
     )
     fsdp_spec = fsdp_axes[0] if len(fsdp_axes) == 1 else (fsdp_axes or None)
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -289,7 +288,7 @@ def _local_moe_sharded_weights(
     C = _capacity(cfg, T)
     n_f = 1
     for a in fsdp_axes:
-        n_f *= compat.axis_size(a)
+        n_f *= jax.lax.axis_size(a)
     d_loc = D // n_f
     idx = jax.lax.axis_index(fsdp_axes)
 
@@ -387,7 +386,7 @@ def moe_ffn(
         return out.reshape(bl, sl, dl), aux
 
     fsdp_spec = fsdp_axes[0] if len(fsdp_axes) == 1 else (fsdp_axes or None)
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax import Array
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.parallel import context as ctx
 
 
@@ -45,7 +44,7 @@ def compressed_psum_mean(x: Array, axis_names: tuple[str, ...]) -> Array:
     """
     k = 1
     for a in axis_names:
-        k *= compat.axis_size(a)
+        k *= jax.lax.axis_size(a)
     if k == 1:
         return x
     shape = x.shape
@@ -96,7 +95,7 @@ def compressed_grad_mean(
             return reduced.astype(gb.dtype), new_r
 
         spec = P()  # grads enter replicated per dp shard group
-        return compat.shard_map(
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(spec, spec),

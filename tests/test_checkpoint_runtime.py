@@ -103,7 +103,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.parallel import context as ctx
 from repro.parallel.compression import compressed_psum_mean
 from repro.runtime.fault_tolerance import remesh
@@ -117,8 +116,8 @@ x = jax.random.normal(jax.random.PRNGKey(0), (4, 64, 32))
 def body(xb):
     return compressed_psum_mean(xb[0], ("data",))[None]
 
-out = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=P("data", None, None),
-                               out_specs=P("data", None, None), check_vma=False))(x)
+out = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data", None, None),
+                            out_specs=P("data", None, None), check_vma=False))(x)
 expect = jnp.broadcast_to(x.mean(axis=0, keepdims=True), x.shape)
 got = np.asarray(out)
 err = np.abs(got - np.asarray(expect)).max() / np.abs(np.asarray(expect)).max()

@@ -9,6 +9,8 @@ machinery itself (partition inference, multiplicities, jit/vmap paths
 and differentiability through ``caps``).
 """
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -94,7 +96,9 @@ def _assert_equivalent(machine, wl, placement, **kwargs):
 @pytest.mark.parametrize("machine", ALL_PRESETS, ids=lambda m: m.name)
 @pytest.mark.parametrize("bench", ["CG", "Swim", "EP", "Page rank"])
 def test_grouped_matches_reference_on_all_presets(machine, bench):
-    rng = np.random.default_rng(hash((machine.name, bench)) % 2**32)
+    # crc32, not hash(): Python salts str hashes per process, which drew
+    # different placements in every run
+    rng = np.random.default_rng(zlib.crc32(f"{machine.name}/{bench}".encode()))
     n = 2 * machine.cores_per_node
     n -= n % machine.n_nodes
     wl = benchmark_workload(bench, n)

@@ -125,7 +125,8 @@ def test_batch_shardings_divisibility():
         "scalar": jax.ShapeDtypeStruct((), jnp.float32),
     }
     sh = mesh_lib.batch_shardings(mesh, tree)
-    assert sh["tokens"].spec[0] == ("data",)  # 4 % 1 == 0 -> data axis
+    # PartitionSpec normalizes a one-axis tuple entry ("data",) to "data"
+    assert sh["tokens"].spec[0] == "data"  # 4 % 1 == 0 -> data axis
     assert sh["scalar"].spec == jax.sharding.PartitionSpec()
 
 

@@ -23,7 +23,9 @@ cfg = dataclasses.replace(
 key = jax.random.PRNGKey(0)
 with ctx.use_mesh(None):
     pass
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+# Auto axes: moe_ffn constrains shardings itself (with_sharding_constraint),
+# which the Explicit axes jax.make_mesh makes by default reject
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 # params must be built under the mesh so the expert factor matches
 with ctx.use_mesh(mesh):

@@ -284,14 +284,17 @@ def test_fully_connected_resource_tensor_is_bitwise_seed(machine, n_per):
 # The noise-FREE arithmetic still matches the per-placement reference
 # bit-tight: tests/test_placement_sweep.py pins evaluate_batch against a
 # simulate() loop at noise_std=0, and test_grouped_solver.py pins the
-# grouped/per-thread equivalence at 1e-6 on raw rates.
+# grouped/per-thread equivalence at 1e-6 on raw rates.  Re-recorded on
+# jax 0.9.0, whose default threefry is the partitionable one: the noise
+# draws changed, the model did not (the old pins still hold under
+# JAX_THREEFRY_PARTITIONABLE=false).
 _SEED_ACCURACY_MEDIANS = {
-    ("E5-2630v3-8c", "Swim"): 0.11666179448366165,
-    ("E5-2630v3-8c", "CG"): 0.17466020584106445,
-    ("E5-2630v3-8c", "NPO"): 0.10933627188205719,
-    ("E5-2699v3-18c", "Swim"): 0.1166609674692154,
-    ("E5-2699v3-18c", "CG"): 0.17466005682945251,
-    ("E5-2699v3-18c", "NPO"): 0.1093355342745781,
+    ("E5-2630v3-8c", "Swim"): 0.2035628706216812,
+    ("E5-2630v3-8c", "CG"): 0.3449532091617584,
+    ("E5-2630v3-8c", "NPO"): 0.18708284199237823,
+    ("E5-2699v3-18c", "Swim"): 0.20356160402297974,
+    ("E5-2699v3-18c", "CG"): 0.344952791929245,
+    ("E5-2699v3-18c", "NPO"): 0.18708154559135437,
 }
 
 
