@@ -24,8 +24,10 @@ advisor's inner loop) never re-profile.
 from __future__ import annotations
 
 import hashlib
+import itertools
 import random as _pyrandom
 import threading
+from collections.abc import Sized
 from functools import lru_cache, partial
 from typing import NamedTuple, Sequence
 
@@ -52,6 +54,7 @@ from repro.core.numa.simulator import (
     thread_class_starts,
 )
 from repro.core.numa.workload import Workload
+from repro.runtime.tracing import span
 
 # ---------------------------------------------------------------------------
 # Placement enumeration: compositions of n_threads over s sockets
@@ -418,66 +421,74 @@ def _evaluate_batch_jit(
 
     Measurement noise is drawn in three batched ``(P, ...)`` draws per
     benchmark (split of the measurement key) instead of a per-placement
-    key chain — same lognormal model, one RNG pass."""
+    key chain — same lognormal model, one RNG pass.
+
+    The fit, the sweep and the noise and error tail run under the named
+    scopes ``fit``, ``sweep`` and ``tail``; a profiler trace names each
+    device operation by its scopes (``tf_op``), and the scopes change
+    nothing else in the program."""
     s = machine.n_nodes
 
     def per_benchmark(arrays, base_key):
         k_prof, k_meas = jax.random.split(base_key)
-        sig, sig_combined, detector = _fit_one(
-            machine, arrays, k_prof, noise_std, background_bw, thread_classes
-        )
+        with jax.named_scope("fit"):
+            sig, sig_combined, detector = _fit_one(
+                machine, arrays, k_prof, noise_std, background_bw, thread_classes
+            )
         wl = Workload("batched", *arrays)
-        sim = simulate_grouped_batch(
-            machine,
-            wl,
-            placements,
-            thread_classes=thread_classes,
-            support=support,
-            slab_id=slab_id,
-            multipath=multipath,
-            bank_assignment=bank_assignment,
-        )
-        read_flows, write_flows = sim.read_flows, sim.write_flows
-        if noise_std > 0.0 or background_bw > 0.0:
-            # the error metrics never read the (noised) instruction
-            # counters, so only the two flow draws are materialized
-            kr, kw = jax.random.split(k_meas)
-            read_flows = read_flows * jnp.exp(
-                noise_std * jax.random.normal(kr, read_flows.shape)
-            ) + background_bw / (s * s)
-            write_flows = write_flows * jnp.exp(
-                noise_std * jax.random.normal(kw, write_flows.shape)
-            ) + background_bw / (s * s)
+        with jax.named_scope("sweep"):
+            sim = simulate_grouped_batch(
+                machine,
+                wl,
+                placements,
+                thread_classes=thread_classes,
+                support=support,
+                slab_id=slab_id,
+                multipath=multipath,
+                bank_assignment=bank_assignment,
+            )
+        with jax.named_scope("tail"):
+            read_flows, write_flows = sim.read_flows, sim.write_flows
+            if noise_std > 0.0 or background_bw > 0.0:
+                # the error metrics never read the (noised) instruction
+                # counters, so only the two flow draws are materialized
+                kr, kw = jax.random.split(k_meas)
+                read_flows = read_flows * jnp.exp(
+                    noise_std * jax.random.normal(kr, read_flows.shape)
+                ) + background_bw / (s * s)
+                write_flows = write_flows * jnp.exp(
+                    noise_std * jax.random.normal(kw, write_flows.shape)
+                ) + background_bw / (s * s)
 
-        local_read = jnp.diagonal(read_flows, axis1=1, axis2=2)  # (P, s)
-        remote_read = read_flows.sum(axis=1) - local_read
-        local_write = jnp.diagonal(write_flows, axis1=1, axis2=2)
-        remote_write = write_flows.sum(axis=1) - local_write
-        totals = jnp.maximum(
-            read_flows.sum(axis=(1, 2)) + write_flows.sum(axis=(1, 2)), 1e-9
-        )
+            local_read = jnp.diagonal(read_flows, axis1=1, axis2=2)  # (P, s)
+            remote_read = read_flows.sum(axis=1) - local_read
+            local_write = jnp.diagonal(write_flows, axis1=1, axis2=2)
+            remote_write = write_flows.sum(axis=1) - local_write
+            totals = jnp.maximum(
+                read_flows.sum(axis=(1, 2)) + write_flows.sum(axis=(1, 2)), 1e-9
+            )
 
-        # batched §4 prediction: the placement-matrix terms are rank-1 in
-        # the bank axis, so the counter errors close over (P, s) math
-        # (guards mirror bwsig's _per_thread_matrix/_interleaved_matrix)
-        nf = placements.astype(jnp.float32)
-        pt = nf / jnp.maximum(nf.sum(axis=1, keepdims=True), 1.0)
-        used = (nf > 0).astype(jnp.float32)
-        il = used / jnp.maximum(used.sum(axis=1, keepdims=True), 1.0)
-        inv = 1.0 / totals[:, None]
-        e_read = inv * _batched_direction_errors(
-            sig.read, pt, il, used,
-            read_flows.sum(axis=2), local_read, remote_read,
-        )
-        e_write = inv * _batched_direction_errors(
-            sig.write, pt, il, used,
-            write_flows.sum(axis=2), local_write, remote_write,
-        )
-        e_comb = inv * _batched_direction_errors(
-            sig_combined.read, pt, il, used,
-            read_flows.sum(axis=2) + write_flows.sum(axis=2),
-            local_read + local_write, remote_read + remote_write,
-        )
+            # batched §4 prediction: the placement-matrix terms are rank-1 in
+            # the bank axis, so the counter errors close over (P, s) math
+            # (guards mirror bwsig's _per_thread_matrix/_interleaved_matrix)
+            nf = placements.astype(jnp.float32)
+            pt = nf / jnp.maximum(nf.sum(axis=1, keepdims=True), 1.0)
+            used = (nf > 0).astype(jnp.float32)
+            il = used / jnp.maximum(used.sum(axis=1, keepdims=True), 1.0)
+            inv = 1.0 / totals[:, None]
+            e_read = inv * _batched_direction_errors(
+                sig.read, pt, il, used,
+                read_flows.sum(axis=2), local_read, remote_read,
+            )
+            e_write = inv * _batched_direction_errors(
+                sig.write, pt, il, used,
+                write_flows.sum(axis=2), local_write, remote_write,
+            )
+            e_comb = inv * _batched_direction_errors(
+                sig_combined.read, pt, il, used,
+                read_flows.sum(axis=2) + write_flows.sum(axis=2),
+                local_read + local_write, remote_read + remote_write,
+            )
         return e_read, e_write, e_comb, totals, detector, sig, sig_combined
 
     return jax.vmap(per_benchmark)(wl_arrays, base_keys)
@@ -510,63 +521,94 @@ def evaluate_batch(
     2-run profiling fit is *not* re-pointed — signatures describe the
     workload, not the placement — so cached signatures stay shared
     across bank assignments.
-    """
-    wl_list = _as_workload_list(workloads)
-    keys = _normalize_keys(keys, len(wl_list))
-    placements = jnp.asarray(placements)
-    support, slab_id = _support_arrays(placements)
 
-    stacked = _stack_workloads(wl_list)
-    e_read, e_write, e_comb, totals, misfit, sigs, csigs = _evaluate_batch_jit(
-        machine,
-        stacked,
-        placements,
-        support,
-        slab_id,
-        keys,
-        float(noise_std),
-        float(background_bw),
-        thread_class_starts(wl_list),
-        multipath,
-        canonical_bank_assignment(machine, bank_assignment),
-    )
-    result = BatchAccuracy(
-        placements=placements,
-        errors_read=e_read,
-        errors_write=e_write,
-        errors_combined=e_comb,
-        total_bw=totals,
-        misfit=misfit,
-        signatures=sigs,
-        combined_signatures=csigs,
-    )
-    # Cache under the *profiling* key each fit actually consumed (the batch
-    # trace splits its base key), so `fitted_signatures` — whose keys ARE
-    # profiling keys — agrees with these entries.  The writeback is skipped
-    # for keys already cached and indexes the stacked trees on host (one
-    # device->host pull of the small signature leaves instead of dozens of
-    # per-benchmark gather dispatches): this tail used to cost more wall
-    # time than the whole jitted solve on repeated sweeps.
-    prof_keys = np.asarray(jax.vmap(lambda k: jax.random.split(k)[0])(keys))
-    cache_keys = [
-        _cache_key(machine, wl, noise_std, background_bw, prof_keys[i])
-        for i, wl in enumerate(wl_list)
-    ]
-    missing = [i for i, ck in enumerate(cache_keys) if _cache_lookup(ck) is None]
-    if missing:
-        sigs_np = jax.tree.map(np.asarray, sigs)
-        csigs_np = jax.tree.map(np.asarray, csigs)
-        misfit_np = np.asarray(misfit)
-        for i in missing:
-            _cache_insert(
-                cache_keys[i],
-                (
-                    _tree_index(sigs_np, i),
-                    _tree_index(csigs_np, i),
-                    misfit_np[i],
-                ),
+    A running profiler records the call as a ``repro.evaluate.batch``
+    span tiled by three children (:mod:`repro.runtime.tracing`):
+    ``repro.evaluate.prepare`` (workloads, keys, support buckets, the
+    stacked arrays, thread classes), ``repro.evaluate.dispatch`` (the
+    jitted call, which returns before the device finishes) and
+    ``repro.evaluate.writeback`` (the signature cache).  All four carry
+    the call's sequence number as ``call``.
+    """
+    call = next(_CALLS)
+    if not isinstance(workloads, (Workload, Sized)):
+        workloads = list(workloads)  # counted here, read in prepare
+    with span(
+        "evaluate.batch", call=call,
+        workloads=1 if isinstance(workloads, Workload) else len(workloads),
+        placements=len(placements),
+    ):
+        with span("evaluate.prepare", call=call):
+            wl_list = _as_workload_list(workloads)
+            keys = _normalize_keys(keys, len(wl_list))
+            placements = jnp.asarray(placements)
+            support, slab_id = _support_arrays(placements)
+            stacked = _stack_workloads(wl_list)
+            thread_classes = thread_class_starts(wl_list)
+            banks = canonical_bank_assignment(machine, bank_assignment)
+        with span("evaluate.dispatch", call=call):
+            e_read, e_write, e_comb, totals, misfit, sigs, csigs = (
+                _evaluate_batch_jit(
+                    machine,
+                    stacked,
+                    placements,
+                    support,
+                    slab_id,
+                    keys,
+                    float(noise_std),
+                    float(background_bw),
+                    thread_classes,
+                    multipath,
+                    banks,
+                )
             )
-    return result
+        with span("evaluate.writeback", call=call):
+            # Cache under the *profiling* key each fit actually consumed
+            # (the batch trace splits its base key), so `fitted_signatures`
+            # — whose keys ARE profiling keys — agrees with these entries.
+            # The writeback is skipped for keys already cached and indexes
+            # the stacked trees on host (one device->host pull of the small
+            # signature leaves instead of dozens of per-benchmark gather
+            # dispatches): this tail used to cost more wall time than the
+            # whole jitted solve on repeated sweeps.
+            prof_keys = np.asarray(
+                jax.vmap(lambda k: jax.random.split(k)[0])(keys)
+            )
+            cache_keys = [
+                _cache_key(machine, wl, noise_std, background_bw, prof_keys[i])
+                for i, wl in enumerate(wl_list)
+            ]
+            missing = [
+                i for i, ck in enumerate(cache_keys) if _cache_lookup(ck) is None
+            ]
+            if missing:
+                sigs_np = jax.tree.map(np.asarray, sigs)
+                csigs_np = jax.tree.map(np.asarray, csigs)
+                misfit_np = np.asarray(misfit)
+                for i in missing:
+                    _cache_insert(
+                        cache_keys[i],
+                        (
+                            _tree_index(sigs_np, i),
+                            _tree_index(csigs_np, i),
+                            misfit_np[i],
+                        ),
+                    )
+        return BatchAccuracy(
+            placements=placements,
+            errors_read=e_read,
+            errors_write=e_write,
+            errors_combined=e_comb,
+            total_bw=totals,
+            misfit=misfit,
+            signatures=sigs,
+            combined_signatures=csigs,
+        )
+
+
+# Numbers each evaluate_batch call, so that the spans of its phases name
+# the call they belong to.
+_CALLS = itertools.count()
 
 
 def _tree_index(tree, i: int):

@@ -844,7 +844,10 @@ def simulate_grouped_batch(
     ``bank_assignment`` applies one page placement (Local-class backing
     node per placement node; ``None`` = node-local) to the whole batch —
     the scheduler evaluates "threads moved, pages stayed" placements
-    through this hook."""
+    through this hook.
+
+    The slab build and the fill run under the named scopes ``slab`` and
+    ``fill``, which the trace reduction reads from each device operation."""
     bank_assignment = canonical_bank_assignment(machine, bank_assignment)
     s = machine.n_nodes
     n = workload.n_threads
@@ -885,7 +888,8 @@ def simulate_grouped_batch(
             lu = jnp.zeros((C, s, 0), dtype)
         return ru, wu, lu
 
-    b_ru, b_wu, b_lu = jax.vmap(bucket_slab)(support)
+    with jax.named_scope("slab"):
+        b_ru, b_wu, b_lu = jax.vmap(bucket_slab)(support)
 
     if n_links:
         # per-link charge of one unit of pt_row flow from node k (the
@@ -912,10 +916,11 @@ def simulate_grouped_batch(
         rem_read = ru * offdiag
         rem_write = wu * offdiag
         mult = _group_multiplicities(starts, n, p).astype(dtype)  # (C, s)
-        x = _progressive_fill_structured(
-            dense, rem_read, rem_write, mult.reshape(G),
-            dense_caps, rr_caps, ww_caps, iterations, early_exit=early_exit,
-        )
+        with jax.named_scope("fill"):
+            x = _progressive_fill_structured(
+                dense, rem_read, rem_write, mult.reshape(G),
+                dense_caps, rr_caps, ww_caps, iterations, early_exit=early_exit,
+            )
         xg = x.reshape(C, s)
         weight = mult * xg
         read_flows = jnp.einsum("ck,ckj->kj", weight, ru, precision=_HI) * elapsed

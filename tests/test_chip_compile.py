@@ -11,6 +11,7 @@ workers would then collect different tests.
 """
 
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -118,20 +119,25 @@ def test_mamba_scan_compiles_for_v5e_at_falcon_mamba_7b_widths(one_chip):
 
 def test_compile_cache_defaults_to_the_checkout(monkeypatch):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    before = (
-        jax.config.jax_compilation_cache_dir,
-        jax.config.jax_persistent_cache_min_compile_time_secs,
+    names = (
+        "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_compilation_cache_include_metadata_in_key",
+        "jax_hlo_source_file_canonicalization_regex",
     )
+    before = {name: getattr(jax.config, name) for name in names}
     try:
         path = compile_cache.use_compile_cache()
         assert path == str(REPO / ".jax_cache")
         assert jax.config.jax_compilation_cache_dir == path
         assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        # cached programs keep their own scopes; paths lose the checkout
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
+        pattern = jax.config.jax_hlo_source_file_canonicalization_regex
+        assert re.sub(pattern, "", str(REPO / "src" / "x.py")) == os.path.join("src", "x.py")
     finally:
-        jax.config.update("jax_compilation_cache_dir", before[0])
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", before[1]
-        )
+        for name, value in before.items():
+            jax.config.update(name, value)
     assert (REPO / ".gitignore").read_text().splitlines().count(".jax_cache/") == 1
 
 

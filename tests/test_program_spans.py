@@ -1,0 +1,151 @@
+"""The program's own trace: spans on the host path of ``evaluate_batch``,
+named scopes in its jitted sweep, and the collector hook
+(``repro.runtime.tracing``)."""
+
+import contextlib
+import gc
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from repro.core.numa import E5_2630_V3
+from repro.core.numa.benchmarks import benchmark_workload
+from repro.core.numa.evaluate import (
+    _evaluate_batch_jit,
+    _stack_workloads,
+    _support_arrays,
+    evaluate_batch,
+    sweep_placements,
+    thread_class_starts,
+)
+
+PHASES = ("repro.evaluate.prepare", "repro.evaluate.dispatch", "repro.evaluate.writeback")
+
+
+@pytest.fixture(scope="module")
+def sweep():
+    workloads = [benchmark_workload("CG", 8), benchmark_workload("Page rank", 8)]
+    placements = sweep_placements(E5_2630_V3, 8)
+    keys = jnp.stack([jax.random.PRNGKey(5), jax.random.PRNGKey(6)])
+    return workloads, placements, keys
+
+
+def _call(sweep):
+    workloads, placements, keys = sweep
+    out = evaluate_batch(E5_2630_V3, workloads, placements, noise_std=0.02, keys=keys)
+    return jax.block_until_ready(out)
+
+
+def _program_spans(trace_dir, work):
+    """``[(name, start_ns, end_ns, stats)]`` of the ``repro.`` host events
+    recorded while ``work()`` ran under the profiler, Python tracer off."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return [
+        (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns), dict(ev.stats))
+        for plane in data.planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for ev in line.events
+        if ev.name.startswith("repro.")
+    ]
+
+
+def test_evaluate_batch_spans_tile_the_call(sweep, tmp_path):
+    _call(sweep)  # compile outside the trace
+    gc.disable()  # no collection between two phases
+    try:
+        spans = _program_spans(tmp_path, lambda: _call(sweep))
+    finally:
+        gc.enable()
+    (batch,) = [sp for sp in spans if sp[0] == "repro.evaluate.batch"]
+    _, b0, b1, stats = batch
+    assert stats["workloads"] == 2 and stats["placements"] == 9
+    children = sorted((sp for sp in spans if sp[0] in PHASES), key=lambda sp: sp[1])
+    assert [sp[0] for sp in children] == list(PHASES)
+    assert all(sp[3]["call"] == stats["call"] for sp in children)
+    assert b0 <= children[0][1] and children[-1][2] <= b1
+    assert all(a[2] <= b[1] for a, b in zip(children, children[1:]))
+    # the parent's self time (outside its children) is the return alone
+    self_ns = (b1 - b0) - sum(e - s for _, s, e, _ in children)
+    assert self_ns < 0.05 * (b1 - b0)
+
+
+def test_calls_are_numbered_in_sequence(sweep, tmp_path):
+    _call(sweep)
+    spans = _program_spans(tmp_path, lambda: (_call(sweep), _call(sweep)))
+    calls = sorted(sp[3]["call"] for sp in spans if sp[0] == "repro.evaluate.batch")
+    assert len(calls) == 2 and calls[1] == calls[0] + 1
+
+
+def _scope_paths(hlo_text: str) -> set[str]:
+    """The named scopes of each location in a lowered program, with the
+    transforms around them (``vmap(...)``, ``jit(...)``) taken away."""
+    scopes = ("fit", "sweep", "slab", "fill", "tail")
+    return {
+        "/".join(t for t in re.split(r"[/()]+", loc) if t in scopes)
+        for loc in re.findall(r'loc\("([^"]*)"', hlo_text)
+    }
+
+
+def _lowered(sweep):
+    workloads, placements, keys = sweep
+    support, slab_id = _support_arrays(placements)
+    return _evaluate_batch_jit.lower(
+        E5_2630_V3, _stack_workloads(workloads), placements, support, slab_id,
+        keys, 0.02, 0.0, thread_class_starts(workloads), False, None,
+    )
+
+
+def test_sweep_trace_carries_named_scopes(sweep):
+    paths = _scope_paths(_lowered(sweep).as_text(debug_info=True))
+    assert {"fit", "sweep/slab", "sweep/fill", "tail"} <= paths
+
+
+def test_named_scopes_leave_outputs_bit_identical(sweep, monkeypatch):
+    """The scopes change the program's metadata alone: traced without them
+    (``jax.named_scope`` made a no-op), the sweep gives the same bits."""
+    with_scopes = _call(sweep)
+    monkeypatch.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    try:
+        assert _scope_paths(_lowered(sweep).as_text(debug_info=True)) <= {""}
+        without = _call(sweep)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for got, want in zip(jax.tree.leaves(with_scopes), jax.tree.leaves(without)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_collections_are_recorded_as_gc_spans(tmp_path):
+    import repro.runtime.tracing  # noqa: F401  (installs the hook)
+
+    spans = _program_spans(tmp_path, gc.collect)
+    generations = [sp[3].get("generation") for sp in spans if sp[0] == "repro.gc"]
+    assert 2 in generations
+
+
+def test_calls_with_an_iterable_and_a_list_of_placements(sweep):
+    """The call's span counts its workloads and placements without
+    converting them: an iterable of workloads (read once) and placements
+    as nested lists give the outputs of the arrays."""
+    workloads, placements, keys = sweep
+    want = _call(sweep)
+    got = evaluate_batch(
+        E5_2630_V3, iter(workloads), np.asarray(placements).tolist(), noise_std=0.02, keys=keys
+    )
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
