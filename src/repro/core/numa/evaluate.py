@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import hashlib
 import itertools
+import math
 import random as _pyrandom
 import threading
 from collections.abc import Sized
@@ -426,7 +427,12 @@ def _evaluate_batch_jit(
     The fit, the sweep and the noise and error tail run under the named
     scopes ``fit``, ``sweep`` and ``tail``; a profiler trace names each
     device operation by its scopes (``tf_op``), and the scopes change
-    nothing else in the program."""
+    nothing else in the program.
+
+    The last output holds, row by row, what the signature cache stores
+    (:func:`_pack_rows`): each benchmark's profiling key (the first half
+    of its base key's split, the key its fit consumed, which the cache
+    files the fit under), its two signatures and its misfit score."""
     s = machine.n_nodes
 
     def per_benchmark(arrays, base_key):
@@ -489,9 +495,42 @@ def _evaluate_batch_jit(
                 read_flows.sum(axis=2) + write_flows.sum(axis=2),
                 local_read + local_write, remote_read + remote_write,
             )
-        return e_read, e_write, e_comb, totals, detector, sig, sig_combined
+        return e_read, e_write, e_comb, totals, detector, sig, sig_combined, k_prof
 
-    return jax.vmap(per_benchmark)(wl_arrays, base_keys)
+    *outs, k_prof = jax.vmap(per_benchmark)(wl_arrays, base_keys)
+    detector, sig, sig_combined = outs[4:]
+    return (*outs, _pack_rows((k_prof, sig, sig_combined, detector)))
+
+
+def _pack_rows(tree) -> Array:
+    """The leaves of ``tree``, which share a leading axis, bitcast to
+    uint32 and laid side by side in one ``(B, K)`` array: the host then
+    fetches them in one transfer, and :func:`_unpack_rows` gives back
+    every bit."""
+    return jnp.concatenate(
+        [
+            jax.lax.bitcast_convert_type(x, jnp.uint32).reshape(x.shape[0], -1)
+            for x in jax.tree.leaves(tree)
+        ],
+        axis=1,
+    )
+
+
+def _unpack_rows(packed: np.ndarray, like):
+    """The tree ``like`` (whose leaves give each shape and dtype) as NumPy
+    arrays read out of the host copy of :func:`_pack_rows`' output."""
+    leaves, treedef = jax.tree.flatten(like)
+    widths = [
+        math.prod(x.shape[1:]) * np.dtype(x.dtype).itemsize // 4 for x in leaves
+    ]
+    columns = np.split(packed, np.cumsum(widths)[:-1], axis=1)
+    return jax.tree.unflatten(
+        treedef,
+        [
+            np.ascontiguousarray(c).view(x.dtype).reshape(x.shape)
+            for c, x in zip(columns, leaves)
+        ],
+    )
 
 
 def evaluate_batch(
@@ -529,6 +568,17 @@ def evaluate_batch(
     jitted call, which returns before the device finishes) and
     ``repro.evaluate.writeback`` (the signature cache).  All four carry
     the call's sequence number as ``call``.
+
+    The writeback files each workload's fit in the signature cache under
+    its profiling key, which the jitted trace returns.  It fetches those
+    keys, the signatures and the misfit scores, packed into one array by
+    the trace, in one transfer (span ``repro.evaluate.fetch``, stat
+    ``leaves``, the arrays fetched), builds the cache
+    keys from memoized workload fingerprints, and inserts the misses
+    (span ``repro.evaluate.insert``, stat ``misses``).  The host path
+    left around the device call is the prepare phase (key normalization,
+    the memo lookups, ``thread_class_starts``) and the jitted call's own
+    dispatch.
     """
     call = next(_CALLS)
     if not isinstance(workloads, (Workload, Sized)):
@@ -547,7 +597,7 @@ def evaluate_batch(
             thread_classes = thread_class_starts(wl_list)
             banks = canonical_bank_assignment(machine, bank_assignment)
         with span("evaluate.dispatch", call=call):
-            e_read, e_write, e_comb, totals, misfit, sigs, csigs = (
+            e_read, e_write, e_comb, totals, misfit, sigs, csigs, packed = (
                 _evaluate_batch_jit(
                     machine,
                     stacked,
@@ -563,28 +613,22 @@ def evaluate_batch(
                 )
             )
         with span("evaluate.writeback", call=call):
-            # Cache under the *profiling* key each fit actually consumed
-            # (the batch trace splits its base key), so `fitted_signatures`
-            # — whose keys ARE profiling keys — agrees with these entries.
-            # The writeback is skipped for keys already cached and indexes
-            # the stacked trees on host (one device->host pull of the small
-            # signature leaves instead of dozens of per-benchmark gather
-            # dispatches): this tail used to cost more wall time than the
-            # whole jitted solve on repeated sweeps.
-            prof_keys = np.asarray(
-                jax.vmap(lambda k: jax.random.split(k)[0])(keys)
+            # The cache files each fit under the profiling key it consumed
+            # (the trace splits each base key and packs the first half),
+            # the key `fitted_signatures` is handed.  The base keys give
+            # the profiling keys' shape and dtype.
+            with span("evaluate.fetch", call=call, leaves=1):
+                rows = np.asarray(packed)
+            prof_keys_np, sigs_np, csigs_np, misfit_np = _unpack_rows(
+                rows, (keys, sigs, csigs, misfit)
             )
-            cache_keys = [
-                _cache_key(machine, wl, noise_std, background_bw, prof_keys[i])
-                for i, wl in enumerate(wl_list)
-            ]
+            cache_keys = _cache_keys(
+                machine, wl_list, noise_std, background_bw, prof_keys_np
+            )
             missing = [
                 i for i, ck in enumerate(cache_keys) if _cache_lookup(ck) is None
             ]
-            if missing:
-                sigs_np = jax.tree.map(np.asarray, sigs)
-                csigs_np = jax.tree.map(np.asarray, csigs)
-                misfit_np = np.asarray(misfit)
+            with span("evaluate.insert", call=call, misses=len(missing)):
                 for i in missing:
                     _cache_insert(
                         cache_keys[i],
@@ -643,30 +687,57 @@ _SIG_LOCK = threading.RLock()
 
 
 def _workload_fingerprint(wl: Workload) -> tuple:
+    """``(name, n_threads, digest of the array fields)``.  Memoized on the
+    workload's identity (the value keeps the workload alive, so its id
+    cannot be recycled) when every array field is a ``jax.Array``, which
+    cannot change; a workload with NumPy fields can be changed in place,
+    so it is digested anew on every call."""
+    arrays = _workload_arrays(wl)
+    immutable = all(isinstance(a, jax.Array) for a in arrays)
+    if immutable:
+        hit = _memo_get(_FINGERPRINT_CACHE, _MEMO_LOCK, id(wl))
+        if hit is not None:
+            return hit[1]
     digest = hashlib.blake2b(digest_size=16)
-    for field in _workload_arrays(wl):
+    for field in arrays:
         a = np.asarray(field)
         digest.update(str(a.shape).encode())
         digest.update(str(a.dtype).encode())
         digest.update(a.tobytes())
-    return (wl.name, wl.n_threads, digest.hexdigest())
+    fingerprint = (wl.name, wl.n_threads, digest.hexdigest())
+    if immutable:
+        _memo_put(
+            _FINGERPRINT_CACHE, _MEMO_LOCK, id(wl), (wl, fingerprint),
+            _MEMO_CACHE_MAX,
+        )
+    return fingerprint
 
 
-def _cache_key(machine, wl, noise_std, background_bw, key) -> tuple:
-    # The machine is content-addressed through its fingerprint: topology
-    # tables (tuple-canonicalized from whatever array form they were built
-    # with) are digested alongside the scalar fields, so two specs with
-    # identical link matrices and routes share cache entries.  Per-node
-    # tuple spellings of core_rate / local_*_bw digest differently from
-    # their scalar equivalents, so a calibration-fitted machine never
-    # collides with the preset it was fitted from.
-    return (
-        machine.fingerprint(),
-        _workload_fingerprint(wl),
-        float(noise_std),
-        float(background_bw),
-        np.asarray(key).tobytes(),
-    )
+_FINGERPRINT_CACHE: dict[int, tuple] = {}
+
+
+def _cache_keys(machine, wl_list, noise_std, background_bw, keys) -> list[tuple]:
+    """One signature-cache key per workload, ``keys`` its profiling keys.
+
+    The machine is content-addressed through its fingerprint: topology
+    tables (tuple-canonicalized from whatever array form they were built
+    with) are digested alongside the scalar fields, so two specs with
+    identical link matrices and routes share cache entries.  Per-node
+    tuple spellings of core_rate / local_*_bw digest differently from
+    their scalar equivalents, so a calibration-fitted machine never
+    collides with the preset it was fitted from."""
+    machine_fp = machine.fingerprint()
+    keys = np.asarray(keys)
+    return [
+        (
+            machine_fp,
+            _workload_fingerprint(wl),
+            float(noise_std),
+            float(background_bw),
+            keys[i].tobytes(),
+        )
+        for i, wl in enumerate(wl_list)
+    ]
 
 
 def _evict_cache_if_full() -> None:
@@ -697,10 +768,6 @@ def _cache_insert(cache_key: tuple, value) -> None:
         _SIG_CACHE[cache_key] = value
         while len(_SIG_CACHE) > _SIG_CACHE_MAX:
             _SIG_CACHE.pop(next(iter(_SIG_CACHE)))
-
-
-def _cache_signatures(machine, wl, noise_std, background_bw, key, value) -> None:
-    _cache_insert(_cache_key(machine, wl, noise_std, background_bw, key), value)
 
 
 @partial(
@@ -734,10 +801,7 @@ def fitted_signatures(
     wl_list = _as_workload_list(workloads)
     keys = _normalize_keys(keys, len(wl_list))
 
-    cache_keys = [
-        _cache_key(machine, wl, noise_std, background_bw, keys[i])
-        for i, wl in enumerate(wl_list)
-    ]
+    cache_keys = _cache_keys(machine, wl_list, noise_std, background_bw, keys)
     results = {}
     for i, ck in enumerate(cache_keys):
         hit = _cache_lookup(ck)

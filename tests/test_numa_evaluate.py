@@ -99,22 +99,72 @@ def test_signature_cache_is_bounded_with_lru_eviction():
         ev._SIG_CACHE.update(saved)
 
 
-def test_workload_and_support_memos_are_bounded():
+@pytest.mark.parametrize("memo", ["stack", "support", "fingerprint"])
+def test_workload_and_support_memos_are_bounded(memo):
     import jax.numpy as jnp
 
     from repro.core.numa import evaluate as ev
 
-    for i in range(ev._MEMO_CACHE_MAX + 40):
-        wl = benchmark_workload("CG", 8)
-        ev._stack_workloads([wl])
-        placements = jnp.asarray(np.asarray([[8 - j, j] for j in range(3)]))
-        ev._support_arrays(placements)
-    assert len(ev._STACK_CACHE) <= ev._MEMO_CACHE_MAX
-    assert len(ev._SUPPORT_CACHE) <= ev._MEMO_CACHE_MAX
-    # memo hit returns the identical stacked value (id-keyed)
-    wl = benchmark_workload("CG", 8)
-    first = ev._stack_workloads([wl])
-    assert ev._stack_workloads([wl]) is first
+    make, memoized, cache = {
+        "stack": (
+            lambda: [benchmark_workload("CG", 8)], ev._stack_workloads,
+            ev._STACK_CACHE,
+        ),
+        "support": (
+            lambda: jnp.asarray(np.asarray([[8 - j, j] for j in range(3)])),
+            ev._support_arrays, ev._SUPPORT_CACHE,
+        ),
+        "fingerprint": (
+            lambda: benchmark_workload("CG", 8), ev._workload_fingerprint,
+            ev._FINGERPRINT_CACHE,
+        ),
+    }[memo]
+    for _ in range(ev._MEMO_CACHE_MAX + 40):
+        memoized(make())
+    assert len(cache) <= ev._MEMO_CACHE_MAX
+    # memo hit returns the identical value (id-keyed)
+    arg = make()
+    first = memoized(arg)
+    assert memoized(arg) is first
+
+
+def _digest_fields(wl) -> str:
+    import hashlib
+
+    digest = hashlib.blake2b(digest_size=16)
+    for field in wl[1:]:
+        a = np.asarray(field)
+        digest.update(str(a.shape).encode())
+        digest.update(str(a.dtype).encode())
+        digest.update(a.tobytes())
+    return digest.hexdigest()
+
+
+def test_fingerprint_of_a_jax_workload_is_its_digest():
+    from repro.core.numa import evaluate as ev
+
+    wl = benchmark_workload("Swim", 8)
+    want = (wl.name, wl.n_threads, _digest_fields(wl))
+    assert ev._workload_fingerprint(wl) == want
+    assert ev._FINGERPRINT_CACHE[id(wl)] == (wl, want)
+    assert ev._workload_fingerprint(wl) == want  # served from the memo
+
+
+def test_fingerprint_of_a_numpy_workload_follows_in_place_changes():
+    """NumPy fields can change under the same workload object, so such a
+    workload is digested on every call and never memoized."""
+    from repro.core.numa import evaluate as ev
+    from repro.core.numa.workload import Workload
+
+    jax_wl = benchmark_workload("CG", 8)
+    wl = Workload(jax_wl.name, *(np.array(f) for f in jax_wl[1:]))
+    before = ev._workload_fingerprint(wl)
+    assert before == ev._workload_fingerprint(jax_wl)
+    wl.read_local[0] += 0.125
+    after = ev._workload_fingerprint(wl)
+    assert after != before
+    assert after == (wl.name, wl.n_threads, _digest_fields(wl))
+    assert id(wl) not in ev._FINGERPRINT_CACHE
 
 
 def test_memo_caches_survive_concurrent_hammer():

@@ -235,6 +235,38 @@ def test_fitted_signature_cache_hits():
     assert c[0] is not a[0]
 
 
+def test_evaluate_batch_fills_the_cache_fitted_signatures_reads(monkeypatch):
+    """Each fit of an ``evaluate_batch`` call is filed under its profiling
+    key, the first half of the split of its base key: ``fitted_signatures``
+    with those keys is answered from the cache alone, and the entries are
+    the call's own signatures and misfit scores, bit for bit."""
+    from repro.core.numa import evaluate as ev
+
+    machine = E7_4830_V3
+    workloads = [benchmark_workload(n, 8) for n in ("CG", "Page rank", "EP")]
+    placements = sweep_placements(machine, 8, max_placements=32, seed=2)
+    keys = jnp.stack([jax.random.PRNGKey(9100 + i) for i in range(3)])
+    monkeypatch.setattr(ev, "_SIG_CACHE", {})
+    batch = evaluate_batch(
+        machine, workloads, placements, noise_std=0.02, keys=keys
+    )
+
+    def no_fit(*args, **kwargs):
+        raise AssertionError("fitted_signatures missed the cache")
+
+    monkeypatch.setattr(ev, "_fit_batch_jit", no_fit)
+    prof_keys = jnp.stack([jax.random.split(k)[0] for k in keys])
+    fits = fitted_signatures(machine, workloads, noise_std=0.02, keys=prof_keys)
+    for i, (sig, csig, misfit) in enumerate(fits):
+        for got, want in (
+            (sig, jax.tree.map(lambda x: x[i], batch.signatures)),
+            (csig, jax.tree.map(lambda x: x[i], batch.combined_signatures)),
+            (misfit, batch.misfit[i]),
+        ):
+            for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+                assert np.asarray(g).tobytes() == np.asarray(w).tobytes()
+
+
 def test_sig_cache_evicts_oldest_and_keeps_hot_keys(monkeypatch):
     """Ordered LRU eviction: filling the cache past its high-water mark
     drops the *oldest* entries, and a key touched mid-fill (LRU hit)

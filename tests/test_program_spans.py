@@ -83,6 +83,75 @@ def test_evaluate_batch_spans_tile_the_call(sweep, tmp_path):
     assert self_ns < 0.05 * (b1 - b0)
 
 
+def test_writeback_fetches_once_and_counts_its_misses(sweep, tmp_path, monkeypatch):
+    """A call with fresh keys files every workload (``misses`` = the
+    workload count); the same keys again file none.  The fetch and the
+    inserts lie inside the writeback."""
+    from repro.core.numa import evaluate as ev
+
+    workloads, placements, _ = sweep
+    _call(sweep)
+    monkeypatch.setattr(ev, "_SIG_CACHE", {})
+    fresh = jnp.stack([jax.random.PRNGKey(7001), jax.random.PRNGKey(7002)])
+
+    def twice():
+        for _ in range(2):
+            jax.block_until_ready(
+                evaluate_batch(E5_2630_V3, workloads, placements, noise_std=0.02, keys=fresh)
+            )
+
+    spans = _program_spans(tmp_path, twice)
+    by_call = {}
+    for sp in spans:
+        by_call.setdefault(sp[3].get("call"), {})[sp[0]] = sp
+    calls = sorted(c for c in by_call if "repro.evaluate.batch" in by_call[c])
+    assert len(calls) == 2
+    misses = []
+    for c in calls:
+        d = by_call[c]
+        _, w0, w1, _ = d["repro.evaluate.writeback"]
+        fetch, insert = d["repro.evaluate.fetch"], d["repro.evaluate.insert"]
+        assert w0 <= fetch[1] and fetch[2] <= insert[1] and insert[2] <= w1
+        assert fetch[3]["leaves"] == 1  # keys, signatures and misfit, packed
+        misses.append(insert[3]["misses"])
+    assert misses == [len(workloads), 0]
+
+
+def test_writeback_pulls_its_rows_in_one_transfer(sweep, monkeypatch):
+    """Once the memos are warm, a call with fresh keys moves what the
+    signature cache stores to the host in one transfer: one ``np.asarray``
+    of the packed rows (2 key words, 2 x 8 signature fields, the misfit
+    score per workload) and no ``jax.device_get``; the call still returns
+    device arrays."""
+    import types
+
+    from repro.core.numa import evaluate as ev
+
+    workloads, placements, _ = sweep
+    _call(sweep)
+    gets, pulls = [], []
+    device_get = jax.device_get
+
+    def counting_get(x):
+        gets.append(len(jax.tree.leaves(x)))
+        return device_get(x)
+
+    def counting_asarray(a, *args, **kwargs):
+        if isinstance(a, jax.Array):
+            pulls.append(a.shape)
+        return np.asarray(a, *args, **kwargs)
+
+    counting_np = types.ModuleType("numpy")
+    counting_np.__dict__.update(np.__dict__)
+    counting_np.asarray = counting_asarray
+    monkeypatch.setattr(jax, "device_get", counting_get)
+    monkeypatch.setattr(ev, "np", counting_np)
+    fresh = jnp.stack([jax.random.PRNGKey(7101), jax.random.PRNGKey(7102)])
+    out = evaluate_batch(E5_2630_V3, workloads, placements, noise_std=0.02, keys=fresh)
+    assert gets == [] and pulls == [(len(workloads), 2 + 2 * 8 + 1)]
+    assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(out))
+
+
 def test_calls_are_numbered_in_sequence(sweep, tmp_path):
     _call(sweep)
     spans = _program_spans(tmp_path, lambda: (_call(sweep), _call(sweep)))
