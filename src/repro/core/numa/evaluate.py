@@ -48,6 +48,7 @@ from repro.core.bwsig import (
 from repro.core.numa.benchmarks import benchmark_workload, suite_names
 from repro.core.numa.machine import MachineSpec, canonical_bank_assignment
 from repro.core.numa.simulator import (
+    noise_factor,
     profile_pair,
     simulate,
     simulate_grouped_batch,
@@ -432,7 +433,9 @@ def _evaluate_batch_jit(
     The last output holds, row by row, what the signature cache stores
     (:func:`_pack_rows`): each benchmark's profiling key (the first half
     of its base key's split, the key its fit consumed, which the cache
-    files the fit under), its two signatures and its misfit score."""
+    files the fit under), its two signatures and its misfit score; and
+    last the most trips the sweep fill's loop took over the benchmark's
+    placements."""
     s = machine.n_nodes
 
     def per_benchmark(arrays, base_key):
@@ -459,10 +462,10 @@ def _evaluate_batch_jit(
                 # the error metrics never read the (noised) instruction
                 # counters, so only the two flow draws are materialized
                 kr, kw = jax.random.split(k_meas)
-                read_flows = read_flows * jnp.exp(
+                read_flows = read_flows * noise_factor(
                     noise_std * jax.random.normal(kr, read_flows.shape)
                 ) + background_bw / (s * s)
-                write_flows = write_flows * jnp.exp(
+                write_flows = write_flows * noise_factor(
                     noise_std * jax.random.normal(kw, write_flows.shape)
                 ) + background_bw / (s * s)
 
@@ -495,11 +498,12 @@ def _evaluate_batch_jit(
                 read_flows.sum(axis=2) + write_flows.sum(axis=2),
                 local_read + local_write, remote_read + remote_write,
             )
-        return e_read, e_write, e_comb, totals, detector, sig, sig_combined, k_prof
+        return (e_read, e_write, e_comb, totals, detector, sig, sig_combined,
+                k_prof, sim.fill_trips.max())
 
-    *outs, k_prof = jax.vmap(per_benchmark)(wl_arrays, base_keys)
+    *outs, k_prof, trips = jax.vmap(per_benchmark)(wl_arrays, base_keys)
     detector, sig, sig_combined = outs[4:]
-    return (*outs, _pack_rows((k_prof, sig, sig_combined, detector)))
+    return (*outs, _pack_rows((k_prof, sig, sig_combined, detector, trips)))
 
 
 def _pack_rows(tree) -> Array:
@@ -567,13 +571,18 @@ def evaluate_batch(
     stacked arrays, thread classes), ``repro.evaluate.dispatch`` (the
     jitted call, which returns before the device finishes) and
     ``repro.evaluate.writeback`` (the signature cache).  All four carry
-    the call's sequence number as ``call``.
+    the call's sequence number as ``call``.  The batch span also carries
+    ``buckets``, the support patterns of the placements (one slab build
+    each), and ``slab_rows``, the rows of one placement's grouped slab:
+    thread classes times nodes, empty groups included.
 
     The writeback files each workload's fit in the signature cache under
     its profiling key, which the jitted trace returns.  It fetches those
     keys, the signatures and the misfit scores, packed into one array by
-    the trace, in one transfer (span ``repro.evaluate.fetch``, stat
-    ``leaves``, the arrays fetched), builds the cache
+    the trace, in one transfer, and reads them out (span
+    ``repro.evaluate.fetch``, stats ``leaves``, the arrays fetched, and
+    ``fill_trips``, the most trips the sweep fill's loop took in the
+    call, the trips of the batched loop), builds the cache
     keys from memoized workload fingerprints, and inserts the misses
     (span ``repro.evaluate.insert``, stat ``misses``).  The host path
     left around the device call is the prepare phase (key normalization,
@@ -587,7 +596,7 @@ def evaluate_batch(
         "evaluate.batch", call=call,
         workloads=1 if isinstance(workloads, Workload) else len(workloads),
         placements=len(placements),
-    ):
+    ) as batch_span:
         with span("evaluate.prepare", call=call):
             wl_list = _as_workload_list(workloads)
             keys = _normalize_keys(keys, len(wl_list))
@@ -596,6 +605,10 @@ def evaluate_batch(
             stacked = _stack_workloads(wl_list)
             thread_classes = thread_class_starts(wl_list)
             banks = canonical_bank_assignment(machine, bank_assignment)
+        batch_span.set_metadata(
+            buckets=int(support.shape[0]),
+            slab_rows=len(thread_classes) * machine.n_nodes,
+        )
         with span("evaluate.dispatch", call=call):
             e_read, e_write, e_comb, totals, misfit, sigs, csigs, packed = (
                 _evaluate_batch_jit(
@@ -617,11 +630,13 @@ def evaluate_batch(
             # (the trace splits each base key and packs the first half),
             # the key `fitted_signatures` is handed.  The base keys give
             # the profiling keys' shape and dtype.
-            with span("evaluate.fetch", call=call, leaves=1):
+            with span("evaluate.fetch", call=call, leaves=1) as fetch_span:
                 rows = np.asarray(packed)
-            prof_keys_np, sigs_np, csigs_np, misfit_np = _unpack_rows(
-                rows, (keys, sigs, csigs, misfit)
-            )
+                trips = jax.ShapeDtypeStruct(misfit.shape, jnp.int32)
+                prof_keys_np, sigs_np, csigs_np, misfit_np, trips_np = _unpack_rows(
+                    rows, (keys, sigs, csigs, misfit, trips)
+                )
+                fetch_span.set_metadata(fill_trips=int(trips_np.max()))
             cache_keys = _cache_keys(
                 machine, wl_list, noise_std, background_bw, prof_keys_np
             )

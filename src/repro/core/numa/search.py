@@ -215,7 +215,7 @@ def relaxed_work_rate(
         [ru.reshape(G, s), wu.reshape(G, s), lu.reshape(G, n_links)], axis=1
     )
     mult = _continuous_multiplicities(classes, n, p)  # (C, s)
-    x = _progressive_fill_structured(
+    x, _ = _progressive_fill_structured(
         dense,
         ru * offdiag,
         wu * offdiag,

@@ -176,13 +176,17 @@ def _digest(*arrays) -> str:
 # tests/test_placement_sweep.py).  The noisy digests (``batch``,
 # ``simnoise``) were re-recorded on jax 0.9.0, whose default threefry is
 # the partitionable one: a new noise stream, same model.  The noise-free
-# ``sim`` digests are unchanged since the pre-refactor code.  Byte
-# digests pin one jax/XLA-CPU build; a jax upgrade may re-record them.
+# ``sim`` digests are unchanged since the pre-refactor code.  The
+# ``batch`` digests were re-recorded again when the noise factor
+# ``exp(noise_std * z)`` became a series exact to float32
+# (``simulator.noise_factor``): the same draws and model, flows differing
+# in their last bits.  Byte digests pin one jax/XLA-CPU build; a jax
+# upgrade may re-record them.
 _PRE_REFACTOR_DIGESTS = {
-    ("E5-2630v3-8c", "batch"): "99d69257b7aaeb5424ac1ed1e74a8355",
+    ("E5-2630v3-8c", "batch"): "b1e994af83afab1b4e81dc05c60bddad",
     ("E5-2630v3-8c", "sim"): "26bc2013541a68d19b0f83cb220ab9d4",
     ("E5-2630v3-8c", "simnoise"): "af2a8dd6886b1229eb47b454d1a97c49",
-    ("E5-2699v3-18c", "batch"): "dc000dd429124d05bf292f4bd628944d",
+    ("E5-2699v3-18c", "batch"): "bb1ce3b16b49c2dae7cbca2cfbf0020a",
     ("E5-2699v3-18c", "sim"): "d129b2fbbb31f4fe72f22f3a7e6ce368",
     ("E5-2699v3-18c", "simnoise"): "903ccf1aafd8544a4b8c650e8b7c72b3",
 }

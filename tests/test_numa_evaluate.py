@@ -224,3 +224,61 @@ def test_enumerate_placements_budget_edges():
     tight = np.asarray(enumerate_placements(m, full - 1))
     assert (tight <= m.cores_per_node).all()
     assert len(tight) == count_placements(m, full - 1) == m.n_nodes
+
+
+def _two_socket_eight_node_host(package_bw=2.0e9, socket_bw=1.2e9):
+    """2 sockets of 4 nodes (2 cores each): 6 + 6 on-package links at one
+    capacity, 4 socket links (node i to node i + 4) at another, and the 24
+    cross-socket pairs of different index routed over two hops, crossing
+    the socket link at their source."""
+    from repro.core.numa.machine import MachineSpec
+    from repro.core.numa.topology import Topology
+
+    ends = [(b + i, b + j) for b in (0, 4) for i in range(4) for j in range(i + 1, 4)]
+    ends += [(i, i + 4) for i in range(4)]
+    bw = [package_bw] * 12 + [socket_bw] * 4
+    index = {frozenset(e): k for k, e in enumerate(ends)}
+    routes = []
+    for i in range(8):
+        for j in range(8):
+            if i == j:
+                path = []
+            elif i // 4 != j // 4 and i % 4 != j % 4:
+                path = [i, i + 4 if i < 4 else i - 4, j]
+            else:
+                path = [i, j]
+            routes.append(tuple(index[frozenset(e)] for e in zip(path[:-1], path[1:])))
+    topo = Topology(name="2s8n", n_nodes=8, link_ends=tuple(ends), link_bw=tuple(bw),
+                    routes=tuple(routes))
+    machine = MachineSpec(
+        name="2s8n", sockets=2, cores_per_socket=8, local_read_bw=5e9, local_write_bw=3e9,
+        remote_read_bw=1.5e9, remote_write_bw=1.2e9, core_rate=(2.2e9,) * 8, topology=topo,
+        hop_attenuation=0.9, nodes_per_socket=4,
+    )
+    machine.validate()
+    return machine
+
+
+def test_evaluate_batch_on_a_routed_eight_node_host_matches_the_reference():
+    """evaluate_batch's grouped fill over a sampled placement space of an
+    8-node, 2-socket host with two-hop routes and links of two capacities
+    gives the per-thread reference's run bandwidth, placement by placement."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.numa.evaluate import count_placements, evaluate_batch
+    from repro.core.numa.simulator import simulate_reference
+
+    machine = _two_socket_eight_node_host()
+    assert machine.topology.hop_matrix().max() == 2
+    assert count_placements(machine, 8) > 64
+    placements = sweep_placements(machine, 8, max_placements=64, seed=3)
+    workloads = [benchmark_workload(name, 8) for name in ("CG", "Swim", "Page rank")]
+    batch = evaluate_batch(machine, workloads, placements)
+    wide = evaluate_batch(_two_socket_eight_node_host(1e15, 1e15), workloads, placements)
+    for b, wl in enumerate(workloads):
+        res = jax.vmap(lambda q: simulate_reference(machine, wl, q))(placements)
+        want = np.asarray(res.read_flows.sum(axis=(1, 2)) + res.write_flows.sum(axis=(1, 2)))
+        np.testing.assert_allclose(np.asarray(batch.total_bw[b]), want, rtol=2e-6)
+    # the links bind: with them widened, some placement moves more
+    assert bool(jnp.any(wide.total_bw > batch.total_bw * (1 + 1e-3)))

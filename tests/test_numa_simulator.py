@@ -13,7 +13,7 @@ from repro.core.numa import (
     pure_workload,
     simulate,
 )
-from repro.core.numa.simulator import _resource_tensor, _thread_nodes, _mix_rows
+from repro.core.numa.simulator import _mix_rows, _resource_tensor, _thread_nodes, noise_factor
 
 
 def test_thread_node_assignment_contiguous():
@@ -139,3 +139,29 @@ def test_property_caps_never_exceeded(n0, bpi, mix):
     assert read[off].max() <= machine.remote_read_bw * (1 + 1e-3)
     assert write[off].max() <= machine.remote_write_bw * (1 + 1e-3)
     assert (np.asarray(res.rates) <= 1 + 1e-6).all()
+
+
+def _ulps(got: np.ndarray, x: np.ndarray) -> np.ndarray:
+    """Distance of float32 ``got`` from the float64 ``exp(x)``, in float32
+    ulp at the exact value."""
+    exact = np.exp(x.astype(np.float64))
+    return np.abs(got.astype(np.float64) - exact) / np.spacing(exact.astype(np.float32))
+
+
+def test_noise_factor_is_exp_within_4_ulp_over_its_series_range():
+    x = np.concatenate([
+        np.linspace(-0.25, 0.25, 200_001, dtype=np.float32),
+        0.02 * np.random.default_rng(0).standard_normal(100_000).astype(np.float32),
+    ])
+    got = np.asarray(jax.jit(noise_factor)(jnp.asarray(x)))
+    assert got.dtype == np.float32
+    assert _ulps(got, x).max() <= 4.0
+
+
+def test_noise_factor_falls_back_to_exp_outside_its_series_range():
+    x = np.asarray([-3.0, -0.2501, 0.2501, 0.5, 2.0], np.float32)
+    got = np.asarray(jax.jit(noise_factor)(jnp.asarray(x)))
+    np.testing.assert_array_equal(got, np.asarray(jnp.exp(jnp.asarray(x))))
+    # the series alone would be off by far more there
+    series = sum(x.astype(np.float64) ** k / np.prod(np.arange(1, k + 1)) for k in range(8))
+    assert np.abs(series[0] - np.exp(-3.0)) / np.exp(-3.0) > 1e-3

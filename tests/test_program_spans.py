@@ -23,6 +23,8 @@ from repro.core.numa.evaluate import (
     sweep_placements,
     thread_class_starts,
 )
+from repro.core.numa.simulator import simulate_grouped_batch
+from repro.core.numa.workload import Workload
 
 PHASES = ("repro.evaluate.prepare", "repro.evaluate.dispatch", "repro.evaluate.writeback")
 
@@ -121,8 +123,8 @@ def test_writeback_pulls_its_rows_in_one_transfer(sweep, monkeypatch):
     """Once the memos are warm, a call with fresh keys moves what the
     signature cache stores to the host in one transfer: one ``np.asarray``
     of the packed rows (2 key words, 2 x 8 signature fields, the misfit
-    score per workload) and no ``jax.device_get``; the call still returns
-    device arrays."""
+    score and the fill's trips per workload) and no ``jax.device_get``;
+    the call still returns device arrays."""
     import types
 
     from repro.core.numa import evaluate as ev
@@ -148,8 +150,81 @@ def test_writeback_pulls_its_rows_in_one_transfer(sweep, monkeypatch):
     monkeypatch.setattr(ev, "np", counting_np)
     fresh = jnp.stack([jax.random.PRNGKey(7101), jax.random.PRNGKey(7102)])
     out = evaluate_batch(E5_2630_V3, workloads, placements, noise_std=0.02, keys=fresh)
-    assert gets == [] and pulls == [(len(workloads), 2 + 2 * 8 + 1)]
+    assert gets == [] and pulls == [(len(workloads), 2 + 2 * 8 + 1 + 1)]
     assert all(isinstance(x, jax.Array) for x in jax.tree.leaves(out))
+
+
+def _one_call_spans(tmp_path, workloads, placements, keys):
+    """``{name: stats}`` of the spans of one warm ``evaluate_batch`` call."""
+
+    def call():
+        jax.block_until_ready(
+            evaluate_batch(E5_2630_V3, workloads, placements, noise_std=0.02, keys=keys)
+        )
+
+    call()
+    return {sp[0]: sp[3] for sp in _program_spans(tmp_path, call)}
+
+
+def test_batch_span_counts_buckets_and_slab_rows(sweep, tmp_path):
+    """The 9 placements ``[k, 8 - k]`` have 3 supports (node 1 alone, both,
+    node 0 alone); CG is one thread class and Page rank's hot and cold
+    halves make two, so the slab has 2 classes x 2 nodes = 4 rows."""
+    workloads, placements, keys = sweep
+    stats = _one_call_spans(tmp_path, workloads, placements, keys)["repro.evaluate.batch"]
+    assert thread_class_starts(workloads) == (0, 4)
+    assert stats["buckets"] == 3
+    assert stats["slab_rows"] == 4
+
+
+@pytest.mark.parametrize(
+    "rows, buckets",
+    [([[4, 4], [5, 3], [3, 5]], 1), ([[8, 0], [7, 1]], 2), ([[8, 0], [0, 8], [2, 6]], 3)],
+)
+def test_buckets_are_the_support_patterns_of_the_placements(sweep, tmp_path, rows, buckets):
+    workloads, _, keys = sweep
+    placements = jnp.asarray(rows, jnp.int32)
+    stats = _one_call_spans(tmp_path, workloads, placements, keys)["repro.evaluate.batch"]
+    assert stats["buckets"] == buckets and stats["placements"] == len(rows)
+
+
+def test_fetch_span_carries_the_fill_trips(sweep, tmp_path):
+    """``fill_trips`` is the most trips the sweep fill's while_loop took
+    over every workload and placement of the call: at least one, at most
+    the fixed-count loop's ``iterations``, and what the fill gives when
+    run on its own."""
+    workloads, placements, keys = sweep
+    spans = _one_call_spans(tmp_path, workloads, placements, keys)
+    trips = spans["repro.evaluate.fetch"]["fill_trips"]
+    classes = thread_class_starts(workloads)
+    fill = jax.jit(
+        lambda arrays: simulate_grouped_batch(
+            E5_2630_V3, Workload("w", *arrays), placements, thread_classes=classes
+        ).fill_trips
+    )
+    want = max(int(np.asarray(fill(tuple(wl[1:]))).max()) for wl in workloads)
+    s, links = E5_2630_V3.n_nodes, E5_2630_V3.topology.n_links
+    iterations = min(len(classes) * s, 2 * s + 2 * s * s + links) + 1
+    assert 1 <= trips <= iterations
+    assert trips == want
+
+
+def test_fill_counts_its_trips(sweep):
+    """The fixed-count loop reports its ``iterations``; the while_loop stops
+    when every group froze, no later, with the same rates."""
+    workloads, placements, _ = sweep
+    classes = thread_class_starts(workloads)
+    wl = workloads[1]
+    fixed = simulate_grouped_batch(
+        E5_2630_V3, wl, placements, thread_classes=classes, early_exit=False
+    )
+    early = simulate_grouped_batch(E5_2630_V3, wl, placements, thread_classes=classes)
+    s, links = E5_2630_V3.n_nodes, E5_2630_V3.topology.n_links
+    iterations = min(len(classes) * s, 2 * s + 2 * s * s + links) + 1
+    assert np.asarray(fixed.fill_trips).tolist() == [iterations] * len(placements)
+    got = np.asarray(early.fill_trips)
+    assert got.min() >= 1 and got.max() <= iterations
+    np.testing.assert_array_equal(np.asarray(early.group_rates), np.asarray(fixed.group_rates))
 
 
 def test_calls_are_numbered_in_sequence(sweep, tmp_path):
