@@ -180,12 +180,11 @@ def relaxed_work_rate(
     topo = machine.topology
     comps = group_slab_components(machine, workload, classes)
     C = comps.base_read.shape[0]
-    G = C * s
     dtype = comps.base_read.dtype
     dense_caps, rr_caps, ww_caps = split_caps(machine)
     offdiag = (1.0 - jnp.eye(s, dtype=dtype))[None, :, :]
     n_links = topo.n_links
-    iterations = min(G, 2 * s + 2 * s * s + n_links) + 1
+    iterations = min(C * s, 2 * s + 2 * s * s + n_links) + 1
 
     p = p.astype(dtype)
     pt_row = p / jnp.maximum(p.sum(), 1.0)
@@ -201,25 +200,20 @@ def relaxed_work_rate(
         + comps.pt_write[:, :, None] * pt_row[None, None, :]
         + comps.il_write[:, :, None] * il_row[None, None, :]
     )
-    if n_links:
-        inc = jnp.asarray(
-            np.asarray(topo.route_incidence(), np.float32).reshape(s, s, n_links)
-        )
-        lu = jnp.einsum(
-            "ckj,kjl->ckl", (ru + wu) * offdiag, inc,
-            precision=jax.lax.Precision.HIGHEST,  # as the exact fill's slab
-        )
-    else:
-        lu = jnp.zeros((C, s, 0), dtype)
-    dense = jnp.concatenate(
-        [ru.reshape(G, s), wu.reshape(G, s), lu.reshape(G, n_links)], axis=1
+    inc = jnp.asarray(
+        np.asarray(topo.route_incidence(), np.float32).reshape(s, s, n_links)
+    )
+    lu = jnp.einsum(
+        "ckj,kjl->ckl", (ru + wu) * offdiag, inc,
+        precision=jax.lax.Precision.HIGHEST,  # as the exact fill's slab
     )
     mult = _continuous_multiplicities(classes, n, p)  # (C, s)
+    # one placement: the fill's trailing placement axis has length 1
     x, _ = _progressive_fill_structured(
-        dense,
-        ru * offdiag,
-        wu * offdiag,
-        mult.reshape(G),
+        ru[..., None],
+        wu[..., None],
+        lu[..., None],
+        mult[..., None],
         dense_caps,
         rr_caps,
         ww_caps,
@@ -227,7 +221,7 @@ def relaxed_work_rate(
         early_exit=False,  # keep the fixed loop: reverse-differentiable
     )
     node_rates = machine.node_rates().astype(dtype)
-    return (mult * x.reshape(C, s) * node_rates[None, :]).sum()
+    return (mult * x[..., 0] * node_rates[None, :]).sum()
 
 
 # ---------------------------------------------------------------------------

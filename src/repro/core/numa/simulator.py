@@ -462,12 +462,15 @@ def _group_multiplicities(
 ) -> Array:
     """``(C, s)`` thread count of class ``c`` on node ``k``: the overlap
     of the static class interval with the traced node interval of the
-    contiguous thread->node assignment."""
+    contiguous thread->node assignment.  ``n_per_node`` may carry trailing
+    placement axes, ``(s, ...)``; the result then has them too,
+    ``(C, s, ...)``."""
     bounds = jnp.asarray(class_starts + (n,), jnp.int32)  # (C+1,) static
-    node_hi = jnp.cumsum(n_per_node.astype(jnp.int32))
+    bounds = bounds.reshape(bounds.shape + (1,) * n_per_node.ndim)
+    node_hi = jnp.cumsum(n_per_node.astype(jnp.int32), axis=0)
     node_lo = node_hi - n_per_node.astype(jnp.int32)
-    lo = jnp.maximum(bounds[:-1, None], node_lo[None, :])
-    hi = jnp.minimum(bounds[1:, None], node_hi[None, :])
+    lo = jnp.maximum(bounds[:-1], node_lo[None])
+    hi = jnp.minimum(bounds[1:], node_hi[None])
     return jnp.maximum(hi - lo, 0)
 
 
@@ -620,9 +623,14 @@ def _progressive_fill_grouped(
 # ``(G, R)`` matrix of :func:`_group_resource_tensor`: each remote path
 # ``(k, j)`` is used only by the C groups living on node ``k``, so the
 # remote constraints stay in ``(C, s, s)`` form (``2*C*s^2`` entries
-# instead of the dense scatter's ``2*C*s^3``) and the fill contracts them
-# with per-node einsums.  The max-min semantics are identical; only the
-# zero padding is gone.
+# instead of the dense scatter's ``2*C*s^3``), and one sum over the
+# classes gives both the bank loads and the remote-path loads.  The
+# max-min semantics are identical; only the zero padding is gone.
+#
+# The batch keeps the placements on the *last* axis of every array.  On a
+# TPU the two minor axes are stored in (8, 128) tiles, so a per-placement
+# ``(s, s)`` block as the minor axes would be padded to ``(8, 128)``; with
+# the placements minor, the lanes are full and the small axes are rows.
 
 
 class GroupSlabs(NamedTuple):
@@ -647,7 +655,7 @@ class GroupedBatchResult(NamedTuple):
     instructions: Array  # (P, s)
     throughput: Array  # (P,) sum of thread rates
     group_rates: Array  # (P, C, s) shared rate of class c on node k
-    fill_trips: Array  # (P,) int32 trips of the fill's loop
+    fill_trips: Array  # (P,) int32 trips the fill took for each placement
 
 
 def group_slab_components(
@@ -723,85 +731,106 @@ def split_caps(
 
 
 def _progressive_fill_structured(
-    dense: Array,  # (G, 2s + L) unit usage: bank reads, bank writes, links
-    rem_read: Array,  # (C, s, s) off-diagonal-masked remote read unit usage
-    rem_write: Array,  # (C, s, s)
-    mult: Array,  # (G,) group multiplicities (float)
-    dense_caps: Array,  # (2s + L,)
+    ru: Array,  # (C, s, s, P) unit read demand of a class-c thread on node k, by bank
+    wu: Array,  # (C, s, s, P) unit write demand
+    lu: Array,  # (C, s, L, P) unit charge on each interconnect link
+    mult: Array,  # (C, s, P) group multiplicities (float)
+    dense_caps: Array,  # (2s + L,) bank reads, bank writes, links
     rr_caps: Array,  # (s, s) inf diagonal
     ww_caps: Array,  # (s, s)
     iterations: int,
     early_exit: bool = False,
 ) -> tuple[Array, Array]:
-    """:func:`_progressive_fill_grouped` over the structured slab: the
-    dense block matmuls while each remote path contracts only the C groups
-    on its source node.  Same freeze rule, bottleneck tolerance and
-    fixed-point; ``early_exit=True`` swaps the fori_loop for a while_loop
-    that stops once every group froze (bit-identical — post-freeze
-    iterations are no-ops — but not reverse-differentiable, so the
-    calibration/search gradient paths keep the fixed-count loop).
+    """:func:`_progressive_fill_grouped` over the structured slab of a
+    whole placement batch, the placements on the trailing axis ``P``.
 
-    Returns the rates and the loop's trip count (int32): the trips the
-    while_loop took, or ``iterations`` for the fixed-count loop.  Under
-    ``vmap`` each lane counts its own trips, and the batched loop runs as
-    long as the largest."""
-    C, s, _ = rem_read.shape
-    g = dense.shape[0]
-    dtype = dense.dtype
+    Each remote path ``(k, j)`` is used only by the groups on node ``k``,
+    so one sum over the classes gives every node's flow toward every bank;
+    the bank loads are its sums over nodes and the remote paths its
+    off-diagonal entries.  Every operand keeps ``P`` as its last axis, so
+    a lane of the device holds one placement and nothing pads the small
+    per-group matrices.  Same freeze rule, bottleneck tolerance and fixed
+    point, lane by lane.
+
+    ``early_exit=True`` swaps the fori_loop for a while_loop that runs
+    until every group of every placement froze.  A trip over a placement
+    whose groups all froze changes nothing (no active group: ``lam*`` is
+    1 and nothing freezes), so each placement's rates are those of a loop
+    run for it alone; the while_loop is not reverse-differentiable, so
+    the gradient paths keep the fixed-count loop.  Call with ``P = 1``
+    (and take ``[..., 0]``) to fill one placement.
+
+    Returns the ``(C, s, P)`` rates and the ``(P,)`` int32 trips each
+    placement took: those in which it still had an active group under
+    the while_loop, ``iterations`` under the fixed-count loop."""
+    C, s, _, P = ru.shape
+    dtype = ru.dtype
+    offdiag = (1.0 - jnp.eye(s, dtype=dtype))[:, :, None]  # (s, s, 1)
+    br_caps = dense_caps[:s, None]
+    bw_caps = dense_caps[s : 2 * s, None]
+    link_caps = dense_caps[2 * s :, None]
+    rr_caps = rr_caps[:, :, None]
+    ww_caps = ww_caps[:, :, None]
+
+    def lam_of(caps, used, act):
+        resid = jnp.maximum(caps - used, 0.0)
+        return jnp.where(act > _EPS, resid / jnp.maximum(act, _EPS), jnp.inf)
 
     def body(state):
         x, frozen, trips = state
         active = ~frozen
-        wt_frozen = (jnp.where(frozen, x, 0.0) * mult).astype(dtype)
-        wt_active = jnp.where(active, mult, 0.0).astype(dtype)
-        fz_dense = jnp.matmul(wt_frozen, dense, precision=_HI)
-        act_dense = jnp.matmul(wt_active, dense, precision=_HI)
-        wf = wt_frozen.reshape(C, s)
-        wa = wt_active.reshape(C, s)
-        fz_rr = jnp.einsum("ck,ckj->kj", wf, rem_read, precision=_HI)
-        act_rr = jnp.einsum("ck,ckj->kj", wa, rem_read, precision=_HI)
-        fz_ww = jnp.einsum("ck,ckj->kj", wf, rem_write, precision=_HI)
-        act_ww = jnp.einsum("ck,ckj->kj", wa, rem_write, precision=_HI)
-
-        def lam_of(resid, act):
-            return jnp.where(
-                act > _EPS, resid / jnp.maximum(act, _EPS), jnp.inf
-            )
-
-        lam_d = lam_of(jnp.maximum(dense_caps - fz_dense, 0.0), act_dense)
-        lam_rr = lam_of(jnp.maximum(rr_caps - fz_rr, 0.0), act_rr)
-        lam_ww = lam_of(jnp.maximum(ww_caps - fz_ww, 0.0), act_ww)
+        # the frozen groups' rates and the active groups' unit weights,
+        # stacked so that one pass over each slab block serves both
+        w = jnp.stack(
+            [jnp.where(frozen, x, 0.0) * mult, jnp.where(active, mult, 0.0)]
+        )[:, :, :, None]  # (2, C, s, 1, P)
+        # (s, s, P): node k's flow toward bank j; (L, P): each link's load
+        fz_r, act_r = (w * ru).sum(1)
+        fz_w, act_w = (w * wu).sum(1)
+        fz_l, act_l = (w * lu).sum((1, 2))
+        lam_br = lam_of(br_caps, fz_r.sum(0), act_r.sum(0))  # (s, P)
+        lam_bw = lam_of(bw_caps, fz_w.sum(0), act_w.sum(0))
+        lam_l = lam_of(link_caps, fz_l, act_l)  # (L, P)
+        lam_rr = lam_of(rr_caps, fz_r * offdiag, act_r * offdiag)  # (s, s, P)
+        lam_ww = lam_of(ww_caps, fz_w * offdiag, act_w * offdiag)
         lam_star = jnp.minimum(
-            jnp.minimum(jnp.min(lam_d), jnp.min(lam_rr)),
-            jnp.minimum(jnp.min(lam_ww), 1.0),
-        )
+            jnp.minimum(
+                jnp.minimum(lam_br.min(0), lam_bw.min(0)),
+                jnp.minimum(lam_rr.min((0, 1)), lam_ww.min((0, 1))),
+            ),
+            jnp.minimum(lam_l.min(0, initial=jnp.inf), 1.0),
+        )  # (P,)
         tol = lam_star * (1.0 + 1e-6)
-        bn_d = lam_d <= tol
-        bn_rr = lam_rr <= tol
-        bn_ww = lam_ww <= tol
+
+        def bottleneck(lam):
+            return (lam <= tol).astype(dtype)
+
+        # bottlenecks a unit of flow from node k to bank j crosses: the
+        # bank and the remote path (never the diagonal, whose lam is inf)
+        hit_r = bottleneck(lam_br)[None] + bottleneck(lam_rr)  # (s, s, P)
+        hit_w = bottleneck(lam_bw)[None] + bottleneck(lam_ww)
         uses = (
-            (dense * bn_d[None, :]).sum(1)
-            + jnp.einsum(
-                "ckj,kj->ck", rem_read, bn_rr.astype(dtype), precision=_HI
-            ).reshape(g)
-            + jnp.einsum(
-                "ckj,kj->ck", rem_write, bn_ww.astype(dtype), precision=_HI
-            ).reshape(g)
-        ) > _EPS
+            (ru * hit_r).sum(2) + (wu * hit_w).sum(2)
+            + (lu * bottleneck(lam_l)).sum(2)
+        ) > _EPS  # (C, s, P)
         freeze_now = active & (uses | (lam_star >= 1.0))
         x = jnp.where(freeze_now, lam_star, x)
-        frozen = frozen | freeze_now
-        return x, frozen, trips + 1
+        return x, frozen | freeze_now, trips + active.any((0, 1))
 
-    state0 = (jnp.zeros((g,), dtype), jnp.zeros((g,), bool), jnp.int32(0))
+    state0 = (
+        jnp.zeros((C, s, P), dtype),
+        jnp.zeros((C, s, P), bool),
+        jnp.zeros((P,), jnp.int32),
+    )
     if early_exit:
         x, frozen, trips = jax.lax.while_loop(
             lambda st: ~jnp.all(st[1]), body, state0
         )
     else:
-        x, frozen, trips = jax.lax.fori_loop(
+        x, frozen, _ = jax.lax.fori_loop(
             0, iterations, lambda _, st: body(st), state0
         )
+        trips = jnp.full((P,), iterations, jnp.int32)
     return jnp.where(frozen, x, 1.0), trips
 
 
@@ -861,8 +890,8 @@ def simulate_grouped_batch(
 ) -> GroupedBatchResult:
     """Ground truth for a whole placement batch in one pass: bucket the
     placements by support pattern, build the base+interleave slab once per
-    bucket, and vmap the structured progressive fill over only the traced
-    multiplicity grids and rank-1 per-thread updates.
+    bucket, and run the structured progressive fill over the whole batch
+    at once, each operand with the placements on its last axis.
 
     ``support`` / ``slab_id`` (from :func:`support_patterns`) may be
     passed in when the caller already bucketed on the host — mandatory
@@ -889,80 +918,68 @@ def simulate_grouped_batch(
         machine, workload, thread_classes, bank_assignment
     )
     C = comps.base_read.shape[0]
-    G = C * s
     dtype = comps.base_read.dtype
     dense_caps, rr_caps, ww_caps = split_caps(machine, caps)
-    offdiag = (1.0 - jnp.eye(s, dtype=dtype))[None, :, :]  # (1, s, s)
     node_rates = machine.node_rates().astype(dtype)
     n_links = topo.n_links
-    if n_links:
-        inc = jnp.asarray(
-            np.asarray(
-                topo.route_incidence(multipath=multipath), np.float32
-            ).reshape(s, s, n_links)
-        )
-    iterations = min(G, 2 * s + 2 * s * s + n_links) + 1
+    inc = jnp.asarray(
+        np.asarray(
+            topo.route_incidence(multipath=multipath), np.float32
+        ).reshape(s, s, n_links)
+    )
+    iterations = min(C * s, 2 * s + 2 * s * s + n_links) + 1
 
-    def bucket_slab(sup):
-        used = sup.astype(dtype)
-        il_row = used / jnp.maximum(used.sum(), 1.0)  # (s,)
-        ru = comps.base_read + comps.il_read[:, :, None] * il_row[None, None, :]
-        wu = comps.base_write + comps.il_write[:, :, None] * il_row[None, None, :]
-        if n_links:
-            cross = (ru + wu) * offdiag
-            lu = jnp.einsum("ckj,kjl->ckl", cross, inc, precision=_HI)
-        else:
-            lu = jnp.zeros((C, s, 0), dtype)
-        return ru, wu, lu
+    def lanes(coeff):  # (C, s) -> (C, s, 1, 1)
+        return coeff[:, :, None, None]
 
+    # Every array from here on has the placements (or the buckets) on its
+    # last axis.
     with jax.named_scope("slab"):
-        b_ru, b_wu, b_lu = jax.vmap(bucket_slab)(support)
+        used = support.T.astype(dtype)  # (s, n_buckets)
+        il_row = used / jnp.maximum(used.sum(0), 1.0)
+        b_ru = comps.base_read[..., None] + lanes(comps.il_read) * il_row
+        b_wu = comps.base_write[..., None] + lanes(comps.il_write) * il_row
+        offdiag = (1.0 - jnp.eye(s, dtype=dtype))[:, :, None]  # (s, s, 1)
+        b_lu = jnp.einsum(
+            "ckjb,kjl->cklb", (b_ru + b_wu) * offdiag, inc, precision=_HI
+        )
 
-    if n_links:
-        # per-link charge of one unit of pt_row flow from node k (the
-        # diagonal rows of inc are all-zero, so no off-diagonal mask needed)
-        def pt_link(pt_row):
-            return jnp.einsum("j,kjl->kl", pt_row, inc, precision=_HI)  # (s, L)
+    nf = placements.T.astype(dtype)  # (s, P)
+    pt_row = nf / jnp.maximum(nf.sum(0), 1.0)
+    # per-link charge of one unit of pt_row flow from node k (the diagonal
+    # rows of inc are all-zero, so no off-diagonal mask needed)
+    pt_link = jnp.einsum("jp,kjl->klp", pt_row, inc, precision=_HI)  # (s, L, P)
+    # each placement's bucket: a gather of whole rows of the bucket-major
+    # table, turned placement-last once, so the cost grows with P alone
+    # even at one bucket per placement (search's leaf batches).  For a v5e
+    # a gather along the last axis compiles to 4x the temp at 8 nodes and
+    # 8192 placements.
+    def bucket_rows(b):  # (..., n_buckets) -> (..., P)
+        rows = b.reshape(-1, b.shape[-1]).T[slab_id]  # (P, R)
+        return rows.T.reshape(b.shape[:-1] + slab_id.shape)
+
+    ru = bucket_rows(b_ru) + lanes(comps.pt_read) * pt_row
+    wu = bucket_rows(b_wu) + lanes(comps.pt_write) * pt_row
+    lu = bucket_rows(b_lu) + lanes(comps.pt_read + comps.pt_write) * pt_link
     starts = tuple(int(v) for v in np.asarray(thread_classes, np.int64))
-
-    def per_placement(p, sid):
-        nf = p.astype(dtype)
-        pt_row = nf / jnp.maximum(nf.sum(), 1.0)
-        ru = b_ru[sid] + comps.pt_read[:, :, None] * pt_row[None, None, :]
-        wu = b_wu[sid] + comps.pt_write[:, :, None] * pt_row[None, None, :]
-        if n_links:
-            lu = b_lu[sid] + (
-                (comps.pt_read + comps.pt_write)[:, :, None]
-                * pt_link(pt_row)[None, :, :]
-            )
-        else:
-            lu = b_lu[sid]
-        dense = jnp.concatenate(
-            [ru.reshape(G, s), wu.reshape(G, s), lu.reshape(G, n_links)], axis=1
+    mult = _group_multiplicities(starts, n, placements.T).astype(dtype)  # (C, s, P)
+    with jax.named_scope("fill"):
+        x, trips = _progressive_fill_structured(
+            ru, wu, lu, mult, dense_caps, rr_caps, ww_caps, iterations,
+            early_exit=early_exit,
         )
-        rem_read = ru * offdiag
-        rem_write = wu * offdiag
-        mult = _group_multiplicities(starts, n, p).astype(dtype)  # (C, s)
-        with jax.named_scope("fill"):
-            x, trips = _progressive_fill_structured(
-                dense, rem_read, rem_write, mult.reshape(G),
-                dense_caps, rr_caps, ww_caps, iterations, early_exit=early_exit,
-            )
-        xg = x.reshape(C, s)
-        weight = mult * xg
-        read_flows = jnp.einsum("ck,ckj->kj", weight, ru, precision=_HI) * elapsed
-        write_flows = jnp.einsum("ck,ckj->kj", weight, wu, precision=_HI) * elapsed
-        instructions = (weight * node_rates[None, :]).sum(0) * elapsed
-        return GroupedBatchResult(
-            read_flows=read_flows,
-            write_flows=write_flows,
-            instructions=instructions,
-            throughput=weight.sum(),
-            group_rates=xg,
-            fill_trips=trips,
-        )
-
-    return jax.vmap(per_placement)(placements, slab_id)
+    weight = (mult * x)[:, :, None]  # (C, s, 1, P)
+    read_flows = (weight * ru).sum(0) * elapsed  # (s, s, P)
+    write_flows = (weight * wu).sum(0) * elapsed
+    instructions = (weight[:, :, 0] * node_rates[:, None]).sum(0) * elapsed
+    return GroupedBatchResult(
+        read_flows=jnp.moveaxis(read_flows, -1, 0),
+        write_flows=jnp.moveaxis(write_flows, -1, 0),
+        instructions=instructions.T,
+        throughput=weight.sum((0, 1, 2)),
+        group_rates=jnp.moveaxis(x, -1, 0),
+        fill_trips=trips,
+    )
 
 
 def simulate(

@@ -93,6 +93,28 @@ def test_evaluate_batch_compiles_for_v5e_at_sweep_size(one_chip):
     assert mem.temp_size_in_bytes < 1 << 30  # far inside one chip's HBM
 
 
+@pytest.mark.parametrize(
+    "cell, temp_limit",
+    [("sweep.e7-4830v3-4s.table1", 40_000_000), ("sweep.epyc7601-2s.table1", 2_500_000_000)],
+)
+def test_sweep_cells_compile_for_v5e_without_tile_padding(
+    one_chip, monkeypatch, cell, temp_limit
+):
+    """Each sweep cell's program, as the benchmark builds it
+    (``bench/tools/aot.py``), within its temp.  The TPU stores an array's
+    two minor axes in (8, 128) tiles, so the fill's per-placement blocks
+    laid out as the minor axes are padded up to 16-fold: 9.1 GB at the
+    EPYC cell's 8192 placements, where the placements on the lanes take
+    0.6 GB.  The layout is the compiler's choice, which the lowered
+    shapes do not show; its temp does."""
+    monkeypatch.syspath_prepend(str(REPO))
+    from bench import core
+    from bench.tools.aot import programs
+
+    ((_, lowered),) = programs(core.load_cell(cell), one_chip)
+    assert lowered.compile().memory_analysis().temp_size_in_bytes <= temp_limit
+
+
 def test_flash_attention_compiles_for_v5e_at_llama3_8b_widths(one_chip):
     from repro.kernels.flash_attention.ops import mha_flash
 

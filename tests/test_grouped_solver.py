@@ -9,6 +9,7 @@ machinery itself (partition inference, multiplicities, jit/vmap paths
 and differentiability through ``caps``).
 """
 
+import re
 import zlib
 
 import jax
@@ -30,9 +31,12 @@ from repro.core.numa import (
     mixed_workload,
     simulate,
     simulate_reference,
+    simulator,
     thread_class_starts,
 )
 from repro.core.numa.benchmarks import benchmark_workload
+from repro.core.numa.evaluate import sweep_placements
+from repro.core.numa.search import _objective_batch_jit
 from repro.core.numa.simulator import (
     _group_multiplicities,
     _group_resource_tensor,
@@ -40,8 +44,11 @@ from repro.core.numa.simulator import (
     _resource_tensor,
     _thread_nodes,
     class_starts_from_arrays,
+    simulate_grouped_batch,
+    support_patterns,
 )
-from repro.core.numa.workload import violator_workload
+from repro.core.numa.workload import Workload, violator_workload
+from test_numa_evaluate import _two_socket_eight_node_host
 
 ALL_PRESETS = [
     E5_2630_V3,
@@ -301,3 +308,132 @@ def test_property_grouped_equals_reference(
         machine, wl, placement,
         noise_std=noise, key=jax.random.PRNGKey(key),
     )
+
+
+# ---------------------------------------------------------------------------
+# the batched fill: the placements on the lanes, one loop for the batch
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize(
+    "host, n_threads", [("routed-8-node", 8), ("E7-4830v3", 24)]
+)
+def test_batch_fill_equals_each_placement_filled_alone(host, n_threads, monkeypatch):
+    """The batch's loop runs until its slowest placement froze; the trips
+    a placement takes after its own groups froze change nothing.  So each
+    placement's rates and trip count equal, bit for bit, those of the fill
+    run over its own slab alone (a trailing placement axis of 1), and all
+    its outputs those of the same program filling only that placement
+    (every lane a copy of it).
+
+    The fill alone is given the batch's slab: XLA's CPU backend fuses a
+    one-placement slab build otherwise, and may round its sums in another
+    order."""
+    machine = _two_socket_eight_node_host() if host == "routed-8-node" else E7_4830_V3
+    wl = benchmark_workload("Page rank", n_threads)
+    classes = thread_class_starts(wl)
+    placements = np.asarray(
+        sweep_placements(machine, n_threads, max_placements=48, seed=5)
+    )
+    support, slab_id = support_patterns(placements)
+    fill = simulator._progressive_fill_structured
+    operands, iterations = [], []
+
+    def recording_fill(*args, **kwargs):  # keeps the batch fill's operands
+        iterations.append(args[7])
+        jax.debug.callback(lambda *a: operands.append(a), *args[:7])
+        return fill(*args, **kwargs)
+
+    monkeypatch.setattr(simulator, "_progressive_fill_structured", recording_fill)
+    # the workload traced, as evaluate_batch passes it
+    run = jax.jit(
+        lambda arrays, p, sid: simulate_grouped_batch(
+            machine, Workload("w", *arrays), p, thread_classes=classes,
+            support=support, slab_id=sid,
+        )
+    )
+    arrays = tuple(wl[1:])
+    batch = run(arrays, placements, slab_id)
+    jax.effects_barrier()
+    slab, caps = operands[0][:4], operands[0][4:]
+    fill_alone = jax.jit(
+        lambda slab, caps: fill(*slab, *caps, iterations[0], early_exit=True)
+    )
+    rates, _ = fill_alone(slab, caps)  # the batch's own fill, replayed
+    np.testing.assert_array_equal(
+        np.moveaxis(np.asarray(rates), -1, 0), np.asarray(batch.group_rates)
+    )
+    trips = np.asarray(batch.fill_trips)
+    assert len(set(trips.tolist())) > 1  # the lanes freeze at different trips
+    P = len(placements)
+    for i in range(P):
+        copies = run(arrays, np.repeat(placements[i : i + 1], P, 0), np.repeat(slab_id[i], P))
+        for name, got, want in zip(batch._fields, batch, copies):
+            np.testing.assert_array_equal(
+                np.asarray(got)[i], np.asarray(want)[0], err_msg=name
+            )
+        rates, alone_trips = fill_alone(
+            tuple(np.asarray(a)[..., i : i + 1] for a in slab), caps
+        )
+        np.testing.assert_array_equal(
+            np.asarray(batch.group_rates)[i], np.asarray(rates)[..., 0]
+        )
+        one = run(arrays, placements[i : i + 1], slab_id[i : i + 1])
+        assert (
+            trips[i] == int(alone_trips[0]) == int(one.fill_trips[0])
+            == int(copies.fill_trips.max())
+        )
+
+
+def test_batch_cost_grows_with_the_placements_alone():
+    """Search scores its leaf batches with one bucket per placement
+    (``slab_id = arange(P)``).  The compiled program's operations and
+    temp then grow in proportion to ``P``: each placement's bucket is
+    picked at a cost that does not grow with the number of buckets."""
+    wl = benchmark_workload("Page rank", 24)
+    classes = thread_class_starts(wl)
+
+    def cost(P):
+        compiled = _objective_batch_jit.lower(
+            E7_4830_V3, tuple(wl[1:]),
+            jax.ShapeDtypeStruct((P, E7_4830_V3.n_nodes), jnp.int32), classes,
+        ).compile()
+        return (
+            compiled.cost_analysis()["flops"],
+            compiled.memory_analysis().temp_size_in_bytes,
+        )
+
+    (flops, temp), (flops_4x, temp_4x) = cost(512), cost(2048)
+    assert flops_4x < 4.5 * flops  # a (P, P) pick would give ~16x
+    assert temp_4x < 4.5 * temp
+
+
+def test_batch_fill_keeps_the_placements_on_the_last_axis():
+    """In the lowered sweep the fill's loop carries every per-placement
+    operand with the placement axis last, and its condition reduces the
+    whole batch's freeze mask to one 0-d predicate: no per-placement
+    predicate, so no per-placement select of the loop state.  This pins
+    the logical shapes; the TPU layout they lead to is bounded by
+    ``test_chip_compile.py``'s compiled temp of each sweep cell."""
+    machine, n_threads, P = E7_4830_V3, 24, 13  # 13: no other axis has it
+    wl = benchmark_workload("Page rank", n_threads)
+    placements = np.asarray(
+        sweep_placements(machine, n_threads, max_placements=P, seed=5)
+    )
+    support, slab_id = support_patterns(placements)
+    lowered = jax.jit(
+        lambda arrays, p, sid: simulate_grouped_batch(
+            machine, Workload("w", *arrays), p,
+            thread_classes=thread_class_starts(wl), support=support, slab_id=sid,
+        )
+    ).lower(tuple(wl[1:]), placements, slab_id)
+    text = lowered.as_text()
+    ((carried, cond),) = re.findall(
+        r"stablehlo\.while\([^\n]*\) : ([^\n]*)\n\s*cond \{\n(.*?)\n\s*\} do", text, re.S
+    )
+    shapes = [t.split("x")[:-1] for t in re.findall(r"tensor<([^>]*)>", carried)]
+    lane_shapes = [d for d in shapes if str(P) in d]
+    assert len(lane_shapes) >= 5  # rates, freeze mask, trips, the slab blocks
+    assert all(d[-1] == str(P) for d in lane_shapes), carried
+    assert re.search(rf"tensor<(\d+x)*{P}xi1>, tensor<i1>\) -> tensor<i1>", cond), cond
+    assert not re.search(rf"tensor<{P}xi1>", cond), cond

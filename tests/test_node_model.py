@@ -180,13 +180,16 @@ def _digest(*arrays) -> str:
 # ``batch`` digests were re-recorded again when the noise factor
 # ``exp(noise_std * z)`` became a series exact to float32
 # (``simulator.noise_factor``): the same draws and model, flows differing
-# in their last bits.  Byte digests pin one jax/XLA-CPU build; a jax
-# upgrade may re-record them.
+# in their last bits.  They were re-recorded once more when the batched
+# fill came to keep the placements on its arrays' last axis and to sum
+# the frozen and active loads in one pass: the same model, a few flows
+# and errors differing in their last bits.  Byte digests pin one
+# jax/XLA-CPU build; a jax upgrade may re-record them.
 _PRE_REFACTOR_DIGESTS = {
-    ("E5-2630v3-8c", "batch"): "b1e994af83afab1b4e81dc05c60bddad",
+    ("E5-2630v3-8c", "batch"): "9fed416dea7fd3ec71eb72a7184425d5",
     ("E5-2630v3-8c", "sim"): "26bc2013541a68d19b0f83cb220ab9d4",
     ("E5-2630v3-8c", "simnoise"): "af2a8dd6886b1229eb47b454d1a97c49",
-    ("E5-2699v3-18c", "batch"): "bb1ce3b16b49c2dae7cbca2cfbf0020a",
+    ("E5-2699v3-18c", "batch"): "19ed8151c09fd85cdb98521aae69d94c",
     ("E5-2699v3-18c", "sim"): "d129b2fbbb31f4fe72f22f3a7e6ce368",
     ("E5-2699v3-18c", "simnoise"): "903ccf1aafd8544a4b8c650e8b7c72b3",
 }
